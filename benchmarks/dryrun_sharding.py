@@ -33,7 +33,8 @@ ARCHES = {
 
 
 def _cell(arch: str) -> dict:
-    env = dict(os.environ)
+    # Fake CPU devices: the child is pinned to the CPU.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (
         os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
@@ -59,6 +60,7 @@ def run() -> list[Row]:
                 name=f"dryrun_sharding/{arch}",
                 us_per_call=res["compile_s"] * 1e6,
                 derived=fmt(
+                    platform="cpu",
                     inter_client_ar=res["inter_client_all_reduces"],
                     all_reduce=counts.get("all-reduce", 0),
                     all_gather=counts.get("all-gather", 0),

@@ -144,7 +144,9 @@ def run() -> list[Row]:
     import sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    # The child runs on fake CPU devices: pinned to the CPU, so it never
+    # reaches for an accelerator this process may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (
         os.path.join(repo, "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
@@ -164,7 +166,8 @@ def run() -> list[Row]:
         Row(
             "kernels/delta_pipeline_sharded",
             b["sharded_us"],
-            fmt(sharded_us=b["sharded_us"], unsharded_us=b["unsharded_us"],
+            fmt(platform="cpu", sharded_us=b["sharded_us"],
+                unsharded_us=b["unsharded_us"],
                 c=b["c"], p=b["p"], devices=res["devices"],
                 gate_matrix_ok=res["ok"]),
         )

@@ -20,7 +20,7 @@ import os
 from benchmarks.common import Row, fmt
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, peaks_for
 from repro.models import build_model
 
 # CWD-relative, matching repro.launch.dryrun's RESULT_DIR (both halves of
@@ -56,18 +56,19 @@ def analyze_cell(rec: dict) -> dict | None:
     if rec.get("status") != "OK":
         return None
     chips = 512 if rec["mesh"].startswith("multipod") else 256
+    peak = peaks_for(PRODUCTION_DEVICE_KIND)
     flops_dev = rec.get("dot_flops", 0.0)
     bytes_dev = rec.get("hbm_bytes", 0.0)
     coll_dev = rec.get("collective_total", 0.0)
-    t_compute = flops_dev / PEAK_FLOPS_BF16
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_compute = flops_dev / peak.flops_bf16
+    t_memory = bytes_dev / peak.hbm_bw
+    t_coll = coll_dev / peak.ici_link_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_total(rec["arch"], rec["shape"])
     useful = mf / max(flops_dev * chips, 1e-9)
     bound = max(terms.values())
-    frac = (mf / chips / PEAK_FLOPS_BF16) / max(bound, 1e-12)
+    frac = (mf / chips / peak.flops_bf16) / max(bound, 1e-12)
     return dict(
         arch=rec["arch"],
         shape=rec["shape"],

@@ -103,6 +103,10 @@ def compare_to_baseline(records, baseline_path, tolerance_pct=25.0) -> int:
 def main() -> None:
     import importlib
 
+    from repro.launch.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     argv = list(sys.argv[1:])
 
     def take_flag(flag):

@@ -26,7 +26,8 @@ grid (G learning rates) × S seeds × R rounds — run four ways:
            wall-based) — compare each only against its own definition.
 
 Two additional WARM-START rows measure the persistent compile cache
-(``REPRO_COMPILE_CACHE_DIR``; a temp dir is used when unset):
+(``REPRO_COMPILE_CACHE_DIR``; unset, the checkout's ``.jax_cache/sweep``,
+emptied before the cold rows):
 
   sweep_warm / async_events_warm : the same sweep/async workloads
            replayed after clearing the IN-PROCESS cache, so every
@@ -40,16 +41,19 @@ Two additional WARM-START rows measure the persistent compile cache
 Wall-clock per row still includes compilation — that is the honest
 end-to-end cost a cold benchmark suite pays; the compile_s/exec_s split
 shows where it goes, and the compile-once cache is exactly what the
-sweep row amortizes across the grid. Also reports the max absolute
+sweep row amortizes across the grid. JAX's own persistent cache
+(``repro.launch.compile_cache``) may still serve XLA compiles left by an
+earlier run; scripts/ci.sh points it at an emptied directory so its cold
+rows compile from nothing. Also reports the max absolute
 accuracy-history deviation between engines as a correctness cross-check.
 """
 from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 import jax
@@ -57,6 +61,7 @@ import numpy as np
 
 from benchmarks.common import Row, SCALE, fmt, preset
 from repro.fl.simulator import FedFogSimulator, SimulatorConfig
+from repro.launch.compile_cache import SWEEP_DIR
 from repro.sim import clear_compile_cache, run_sweep
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -81,30 +86,24 @@ def run() -> list[Row]:
     base_rounds = n_seeds * rounds  # single-config sim-rounds
     grid_rounds = g * base_rounds  # grid-workload sim-rounds
 
-    # Persistent warm-start cache: honor the caller's directory (the CI
-    # cold→warm double pass shares one), else a private temp dir so the
-    # warm rows below still measure the disk round trip. A self-created
-    # temp dir is torn back down afterwards — env var, the global jax
-    # compilation-cache config and the directory itself — so suites
-    # running after this one in the same harness process are untouched.
-    own_tmp = None
-    if not os.environ.get("REPRO_COMPILE_CACHE_DIR"):
-        own_tmp = tempfile.mkdtemp(prefix="repro-compile-cache-")
-        os.environ["REPRO_COMPILE_CACHE_DIR"] = own_tmp
+    # Persistent warm-start cache: the caller's directory, else the
+    # checkout's fixed one, emptied before a cold pass so the cold rows
+    # are cold (a warm-only pass reads what the last cold pass left).
+    # The variable is put back afterwards, so later suites in the same
+    # harness process do not serialize their sweeps.
+    owned = not os.environ.get("REPRO_COMPILE_CACHE_DIR")
+    if owned:
+        os.environ["REPRO_COMPILE_CACHE_DIR"] = SWEEP_DIR
     try:
         if os.environ.get("REPRO_BENCH_WARM", "0") == "1":
             return _warm_rows(base, lrs, n_seeds, rounds, p, grid_rounds)
+        if owned:
+            shutil.rmtree(SWEEP_DIR, ignore_errors=True)
         return _cold_and_warm_rows(base, lrs, n_seeds, rounds, p,
                                    grid_rounds, g)
     finally:
-        if own_tmp is not None:
-            import shutil
-
-            from repro.sim.sweep import disable_xla_cache
-
+        if owned:
             os.environ.pop("REPRO_COMPILE_CACHE_DIR", None)
-            disable_xla_cache()
-            shutil.rmtree(own_tmp, ignore_errors=True)
 
 
 def _cold_and_warm_rows(
@@ -347,9 +346,10 @@ def _sharded_row(lrs, rounds, p) -> Row:
     """``sweep_sharded``: the grouped lr-grid sweep with its seed batch
     sharded across 8 fake CPU devices (``run_sweep(devices=8)``), via a
     subprocess worker (the fake-device flag must precede jax init). One
-    seed per device, so the executable's seed axis is fully parallel."""
+    seed per device, so the executable's seed axis is fully parallel.
+    The worker is pinned to the CPU, so the row is a CPU-host row."""
     n_seeds = 8
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (
         os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
@@ -375,7 +375,8 @@ def _sharded_row(lrs, rounds, p) -> Row:
         f"compile_s={res['compile_s']:.2f};"
         f"exec_s={res['exec_s']:.2f};"
         f"acc_mean={res['acc_mean']:.4g};"
-        + fmt(devices=res["devices"], grid=len(lrs), seeds=n_seeds,
+        + fmt(platform="cpu", devices=res["devices"], grid=len(lrs),
+              seeds=n_seeds,
               rounds=rounds, clients=p["clients"]),
     )
 

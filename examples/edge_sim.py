@@ -29,6 +29,7 @@ bitwise.
 import argparse
 
 from repro.fl.simulator import FedFogSimulator, SimulatorConfig
+from repro.launch.compile_cache import use_persistent_cache
 from repro.obs import MetricTap, NoopTracker, tracker_from_spec
 from repro.sim.faults import FaultConfig
 
@@ -193,6 +194,7 @@ def main():
                     help="tap decimation: emit every k-th round/flush")
     args = ap.parse_args()
 
+    use_persistent_cache()
     tracker = tracker_from_spec(args.track)
     with tracker:
         _run(args, tracker)

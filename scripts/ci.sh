@@ -120,21 +120,25 @@ if [[ "${REPRO_BENCH_RECORD:-0}" == 1 || ! -f BENCH_simulator.json ]]; then
 elif [[ "${REPRO_BENCH_COMPARE:-1}" != 1 ]]; then
   BENCH_ARGS=""
 fi
-# The cold pass populates a persistent compile cache
-# (REPRO_COMPILE_CACHE_DIR) that the warm pass below — a FRESH process —
-# must hit: serialized sweep executables make the second process skip
-# tracing and XLA compilation entirely (n_compiles=0). Bench history
-# (benchmarks.history) is pointed at a temp file so a CI smoke never
-# pollutes the real BENCH_history.jsonl trajectory.
-CACHE_DIR="${REPRO_COMPILE_CACHE_DIR:-$(mktemp -d)}"
+# The cold pass populates a persistent compile cache that the warm pass
+# below — a FRESH process — must hit: serialized sweep executables make
+# the second process skip tracing and XLA compilation entirely
+# (n_compiles=0). Unless the caller names one (REPRO_COMPILE_CACHE_DIR),
+# the bench uses the checkout's .jax_cache/sweep and empties it before
+# the cold pass. JAX's own persistent cache goes to an emptied directory
+# too, so the cold pass's XLA compiles are not served by an earlier run.
+# Bench history (benchmarks.history) is pointed at a temp file so a CI
+# smoke never pollutes the real BENCH_history.jsonl trajectory.
+XLA_CACHE_DIR=.jax_cache/ci
+rm -rf "$XLA_CACHE_DIR"
 HIST_FILE="$(mktemp)"
-REPRO_BENCH_HISTORY="$HIST_FILE" REPRO_COMPILE_CACHE_DIR="$CACHE_DIR" \
+REPRO_BENCH_HISTORY="$HIST_FILE" JAX_COMPILATION_CACHE_DIR="$XLA_CACHE_DIR" \
   REPRO_BENCH_SCALE=quick PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m benchmarks.run simulator_engine serving $BENCH_ARGS
 
-echo "=== warm-start pass (fresh process, persistent cache at $CACHE_DIR) ==="
+echo "=== warm-start pass (fresh process, the cold pass's cache) ==="
 WARM_LOG="$(mktemp)"
-REPRO_BENCH_WARM=1 REPRO_COMPILE_CACHE_DIR="$CACHE_DIR" \
+REPRO_BENCH_WARM=1 JAX_COMPILATION_CACHE_DIR="$XLA_CACHE_DIR" \
   REPRO_BENCH_SCALE=quick PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m benchmarks.run simulator_engine | tee "$WARM_LOG"
 for row in sweep_warm async_events_warm; do

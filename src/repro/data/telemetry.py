@@ -79,7 +79,10 @@ def init_telemetry(cfg: TelemetryConfig) -> ClientTelemetry:
         cpu=u(ks[0], 0.4, 1.0),
         mem=u(ks[1], 0.4, 1.0),
         batt=batt,
-        energy=batt,  # normalized energy level tracks battery
+        # Normalized energy level tracks battery. Its own buffer: the
+        # engines donate the telemetry carry, and one buffer may not be
+        # donated twice.
+        energy=batt.copy(),
     )
 
 

@@ -40,9 +40,10 @@ _COLLECTIVE_KINDS = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# "name = TYPE opcode(": TYPE may be a tuple and carry TPU tiled layouts
+# such as ``{3,2,1,0:T(8,128)(2,1)}``; it ends at the first " opcode(".
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*((?:\([^)]*\)|[\w\[\],{}0-9]+?))\s+"
-    r"([\w\-]+)\("
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s+([\w\-]+)\("
 )
 _GROUPS_EXPLICIT_RE = re.compile(r"replica_groups=\{(\{[\d,{} ]*\})\}")
 _GROUPS_IOTA_RE = re.compile(

@@ -100,14 +100,21 @@ class MeshPlan:
     # ------------------------------------------------------------------ #
     def build_mesh(self, devices=None):
         """Materialize the plan as a jax Mesh (first ``device_count``
-        local devices unless an explicit device array is given)."""
+        local devices unless an explicit device array is given).
+
+        Every axis is ``Auto``: the round pins layouts with
+        ``with_sharding_constraint``, which only accepts Auto axes, and
+        ``jax.make_mesh`` defaults to Explicit axes."""
         import jax
         import numpy as np
 
+        auto = (jax.sharding.AxisType.Auto,) * len(self.axis_names)
         if devices is None:
-            return jax.make_mesh(self.axis_sizes, self.axis_names)
+            return jax.make_mesh(
+                self.axis_sizes, self.axis_names, axis_types=auto
+            )
         devs = np.asarray(devices).reshape(self.axis_sizes)
-        return jax.sharding.Mesh(devs, self.axis_names)
+        return jax.sharding.Mesh(devs, self.axis_names, axis_types=auto)
 
 
 def plan_for(

@@ -342,18 +342,20 @@ def make_rules(
     multi_pod: bool = False,
     zero: int | None = None,
     device_count: int | None = None,
+    devices=None,
 ) -> ShardingRules:
     """Build the plan + plan-shaped mesh + rules for one config.
 
     ``mesh`` may be the production (pod ×) data × model mesh from
     launch/mesh.py — its devices are re-laid-out onto the plan's axes —
-    or None to allocate ``plan.device_count`` local devices directly.
+    or None to lay the plan over ``devices`` (default: the first
+    ``plan.device_count`` local devices).
     """
     plan = plan_for(
         cfg, multi_pod=multi_pod, device_count=device_count, zero=zero
     )
     if mesh is None:
-        mesh = plan.build_mesh()
+        mesh = plan.build_mesh(devices)
     elif tuple(getattr(mesh, "axis_names", ())) != plan.axis_names:
         import numpy as np
 

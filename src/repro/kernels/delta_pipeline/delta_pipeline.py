@@ -44,8 +44,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, interpret_default
+from repro.kernels.pallas_compat import interpret_default
 
 DEFAULT_BLOCK_D = 2048
 _EPS = 1e-12  # matches core.aggregation._EPS / sim.events.staleness
@@ -83,7 +84,7 @@ def delta_sq_norms(
         in_specs=[pl.BlockSpec((c, block_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((c,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((c,), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(updates)
 
@@ -115,60 +116,66 @@ def _transform_tile(x, pre_ref, seg_ref, tab_ref, compression, n_leaves):
     return x
 
 
-def _bitonic_sort(x):
-    """Ascending sort along axis 0 via a static bitonic compare-exchange
-    network (axis-0 extent must be a power of two; callers pad with
-    +inf). Produces the exact same sorted VALUES as ``jnp.sort`` — the
-    sorted sequence of a float multiset is unique — which is what makes
-    the in-kernel median/trimmed selection bitwise-equal to the
-    ``core.aggregation`` references. Pure where/compare ops, so it
-    lowers on TPU where ``sort`` does not."""
-    n = x.shape[0]
-    tail = (None,) * (x.ndim - 1)
+def _bitonic_sort(rows):
+    """Ascending sort of a list of equal-shape rows, elementwise across
+    the list, via a static bitonic compare-exchange network (the list
+    length must be a power of two; callers pad with +inf rows).
+    Produces the exact same sorted VALUES as ``jnp.sort`` over the row
+    axis — the sorted sequence of a float multiset is unique — which is
+    what makes the in-kernel median/trimmed selection bitwise-equal to
+    the ``core.aggregation`` references. Each exchange is a min/max of
+    two whole rows with static partners, so the network lowers on TPU
+    without ``sort`` or a gather."""
+    rows = list(rows)
+    n = len(rows)
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            idx = jnp.arange(n)
-            partner = idx ^ j
-            keep_min = (idx < partner) == ((idx & k) == 0)
-            px = x[partner]
-            lo = jnp.where(x <= px, x, px)
-            hi = jnp.where(x <= px, px, x)
-            x = jnp.where(keep_min[(...,) + tail], lo, hi)
+            for i in range(n):
+                p = i ^ j
+                if p < i:
+                    continue
+                a, b = rows[i], rows[p]
+                lo = jnp.where(a <= b, a, b)
+                hi = jnp.where(a <= b, b, a)
+                rows[i], rows[p] = (lo, hi) if (i & k) == 0 else (hi, lo)
             j //= 2
         k *= 2
-    return x
+    return rows
 
 
-def _select_aggregate(x, sel, cnt_ref, aggregator):
+def _select_aggregate(x, wn, cnt, aggregator):
     """Masked coordinate-wise median / trimmed mean over the client axis
     of one (C, bd) tile — bitwise ``core.aggregation.median_aggregate``/
     ``trimmed_mean_aggregate`` semantics (+inf sentinel sort, identical
-    index arithmetic). ``cnt_ref`` is the (1, 2) int32 [num_sel, k_trim]
+    index arithmetic, row sums in row order). ``wn`` is the (1, C) 0/1
+    participation row and ``cnt`` the (1, 2) int32 [num_sel, k_trim]
     pair, traced data so participation masks stay dynamic."""
     c = x.shape[0]
-    big = jnp.where(sel[:, None], x, jnp.inf)
     n2 = 1 << max((c - 1).bit_length(), 0)
-    if n2 > c:  # pad the client axis to a power of two for the network
-        big = jnp.concatenate(
-            [big, jnp.full((n2 - c,) + x.shape[1:], jnp.inf, big.dtype)],
-            axis=0,
-        )
-    s = _bitonic_sort(big)
-    num_sel = cnt_ref[0, 0]
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    inf_row = jnp.full((1,) + x.shape[1:], jnp.inf, x.dtype)
+    rows = [
+        jnp.where(wn[:, i:i + 1] > 0.0, x[i:i + 1], jnp.inf)
+        for i in range(c)
+    ] + [inf_row] * (n2 - c)
+    s = _bitonic_sort(rows)
+    num_sel = cnt[:, 0:1]  # (1, 1): compared as vectors, no scalar reads
     if aggregator == "median":
         lo_idx = jnp.maximum((num_sel - 1) // 2, 0)
         hi_idx = num_sel // 2
-        lo = jnp.sum(jnp.where(row == lo_idx, s, 0.0), axis=0)
-        hi = jnp.sum(jnp.where(row == hi_idx, s, 0.0), axis=0)
-        return 0.5 * (lo + hi)
-    k_trim = cnt_ref[0, 1]
-    keep = (row >= k_trim) & (row < num_sel - k_trim)
-    total = jnp.sum(jnp.where(keep, s, 0.0), axis=0)
-    cnt = jnp.maximum(num_sel - 2 * k_trim, 1).astype(jnp.float32)
-    return total / cnt
+        lo = hi = jnp.zeros_like(inf_row)
+        for i, r in enumerate(s):
+            lo = lo + jnp.where(lo_idx == i, r, 0.0)
+            hi = hi + jnp.where(hi_idx == i, r, 0.0)
+        return (0.5 * (lo + hi))[0]
+    k_trim = cnt[:, 1:2]
+    total = jnp.zeros_like(inf_row)
+    for i, r in enumerate(s):
+        keep = (k_trim <= i) & (num_sel - k_trim > i)
+        total = total + jnp.where(keep, r, 0.0)
+    n_keep = jnp.maximum(num_sel - 2 * k_trim, 1).astype(jnp.float32)
+    return (total / n_keep)[0]
 
 
 # --------------------------------------------------------------------- #
@@ -205,13 +212,14 @@ def _make_pipeline_kernel(
         x = _transform_tile(x, pre_ref, seg_ref, tab_ref, compression,
                             n_leaves)
         if robust:
-            agg = _select_aggregate(
-                x, wn_ref[0, :] > 0.0, cnt_ref, aggregator
-            )
+            agg = _select_aggregate(x, wn_ref[...], cnt_ref[...], aggregator)
         else:
+            # HIGHEST: the MXU would otherwise round the f32 deltas to
+            # one bf16 pass (~2^-9 relative) on TPU; Eq. 6 is an f32 sum.
             agg = jax.lax.dot_general(
                 wn_ref[0, :][None, :].astype(jnp.float32), x,
                 (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )[0]  # (bd,)
         if has_dp:
@@ -439,7 +447,7 @@ def delta_pipeline_apply(
         in_specs=in_specs,
         out_specs=out_specs if has_mu else out_specs[0],
         out_shape=out_shape if has_mu else out_shape[0],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
     if has_mu:
@@ -466,6 +474,7 @@ def _make_partial_kernel(n_leaves: int, has_pre: bool, compression: str):
         out_ref[...] = jax.lax.dot_general(
             dm_ref[0, :][None, :].astype(jnp.float32), x,
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # f32 sum, as above
             preferred_element_type=jnp.float32,
         )[0]
 
@@ -539,7 +548,7 @@ def delta_pipeline_partial(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp_total,), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
     return out[:d]

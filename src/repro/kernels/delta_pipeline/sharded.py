@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.delta_pipeline.delta_pipeline import (
@@ -259,12 +258,12 @@ def delta_pipeline_apply_sharded(
             mu2 = jnp.zeros((), jnp.float32)
         return out, mu2
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(cxp, rep, row, row, rep, row, rep, rep, rep),
         out_specs=(rep, rep),
-        check_rep=False,
+        check_vma=False,
     )
     out, mu2 = mapped(
         updates, base, mask, weights, lr_in, stale_in, sexp_in,

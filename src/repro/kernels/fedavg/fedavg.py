@@ -21,8 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, interpret_default
+from repro.kernels.pallas_compat import interpret_default
 
 DEFAULT_BLOCK_D = 2048
 
@@ -75,7 +76,7 @@ def fedavg_apply(
         ],
         out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp,), base.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_KV = 512
 _NEG_INF = -1e30
@@ -166,7 +164,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,7 +17,7 @@ from repro.kernels.pallas_compat import interpret_default
 
 def paged_attention(
     q: jax.Array,  # (S, H, hd)
-    k_pages: jax.Array,  # (P, page, Hkv, hd)
+    k_pages: jax.Array,  # (P, Hkv, page, hd)
     v_pages: jax.Array,
     page_table: jax.Array,  # (S, pages_per_slot) int32
     lengths: jax.Array,  # (S,) int32 — valid tokens per slot incl. current
@@ -26,7 +26,7 @@ def paged_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     s, h, hd = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = h // hkv
     assert g * hkv == h, (h, hkv)
     win = int(window) if window is not None else -1

@@ -18,6 +18,11 @@ page indirection resolved *in the HBM pass*:
   * GQA via the q reshape (S, Hkv, groups, hd): each grid step scores
     one kv head's ``groups`` query heads against one page — the kv page
     is read once per kv head, never repeated.
+  * the pool is laid out ``(P, Hkv, page, hd)`` so one block is a whole
+    ``(page, hd)`` tile of one kv head: the TPU compiler tiles the last
+    two block dims, and a single-head slice of a ``(page, Hkv, hd)`` page
+    (second-minor dim 1 of Hkv) is refused for head counts such as
+    hymba's 5.
 
 Validated bitwise-adjacent (fp32 tolerance: online softmax reassociates)
 against ``ref.paged_attention_ref`` in interpret mode across archetypes
@@ -32,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -41,8 +44,8 @@ def _decode_kernel(
     tab_ref,  # (S, n_pages) int32 SMEM — scalar-prefetched page table
     len_ref,  # (S,) int32 SMEM — valid tokens per slot (incl. current)
     q_ref,  # (1, 1, g, hd) VMEM
-    k_ref,  # (1, page, 1, hd) VMEM — physical page tab[s, p]
-    v_ref,  # (1, page, 1, hd) VMEM
+    k_ref,  # (1, 1, page, hd) VMEM — physical page tab[s, p], one kv head
+    v_ref,  # (1, 1, page, hd) VMEM
     o_ref,  # (1, 1, g, hd) VMEM
     m_scratch,  # (g, 128) f32 — running max, lane-broadcast
     l_scratch,  # (g, 128) f32 — running denominator
@@ -74,9 +77,14 @@ def _decode_kernel(
     def _compute():
         g = q_ref.shape[2]
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (g, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
+        k = k_ref[0, 0].astype(jnp.float32)  # (page, hd)
+        # HIGHEST keeps both dots in f32 on the MXU (a default-precision
+        # f32 dot may round its operands to bf16); decode is bound by the
+        # page reads, not by these (g, page) products.
         scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )  # (g, page)
         k_pos = first_k + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
         mask = k_pos <= q_pos
@@ -96,9 +104,10 @@ def _decode_kernel(
         l_new = l_prev * corr + jnp.broadcast_to(
             jnp.sum(probs, axis=-1, keepdims=True), l_prev.shape
         )
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
+        v = v_ref[0, 0].astype(jnp.float32)  # (page, hd)
         pv = jax.lax.dot_general(
             probs, v, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )  # (g, hd)
         acc_scratch[...] = acc_scratch[...] * corr[:, :1] + pv
@@ -118,7 +127,7 @@ def _decode_kernel(
 )
 def paged_attention_fwd(
     q: jax.Array,  # (S, Hkv, g, hd) — query heads grouped under kv heads
-    k_pages: jax.Array,  # (P, page, Hkv, hd)
+    k_pages: jax.Array,  # (P, Hkv, page, hd)
     v_pages: jax.Array,
     page_table: jax.Array,  # (S, pages_per_slot) int32
     lengths: jax.Array,  # (S,) int32
@@ -127,7 +136,7 @@ def paged_attention_fwd(
     interpret: bool = False,
 ) -> jax.Array:
     s, hkv, g, hd = q.shape
-    _, page, _, _ = k_pages.shape
+    _, _, page, _ = k_pages.shape
     n_pages = page_table.shape[1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -136,12 +145,12 @@ def paged_attention_fwd(
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda ss, hh, pp, tab, ln: (ss, hh, 0, 0)),
             pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda ss, hh, pp, tab, ln: (tab[ss, pp], 0, hh, 0),
+                (1, 1, page, hd),
+                lambda ss, hh, pp, tab, ln: (tab[ss, pp], hh, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda ss, hh, pp, tab, ln: (tab[ss, pp], 0, hh, 0),
+                (1, 1, page, hd),
+                lambda ss, hh, pp, tab, ln: (tab[ss, pp], hh, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -160,7 +169,7 @@ def paged_attention_fwd(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hkv, g, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,20 +18,21 @@ Array = jax.Array
 
 
 def gather_pages(pages: Array, page_table: Array) -> Array:
-    """(P, page, Hkv, hd) pool + (S, n) table -> contiguous (S, n*page, Hkv, hd).
+    """(P, Hkv, page, hd) pool + (S, n) table -> contiguous (S, n*page, Hkv, hd).
 
     Logical pages are gathered in table order, so position ``t`` of slot
     ``s`` lands at row ``t`` — identical layout to a contiguous KV cache.
     """
     s, n = page_table.shape
-    g = pages[page_table]  # (S, n, page, Hkv, hd)
-    return g.reshape(s, n * pages.shape[1], *pages.shape[2:])
+    _, hkv, page, hd = pages.shape
+    g = jnp.swapaxes(pages[page_table], 2, 3)  # (S, n, page, Hkv, hd)
+    return g.reshape(s, n * page, hkv, hd)
 
 
 def paged_attention_ref(
     q: Array,  # (S, H, hd) — one query token per slot
-    k_pages: Array,  # (P, page, Hkv, hd) physical page pool
-    v_pages: Array,  # (P, page, Hkv, hd)
+    k_pages: Array,  # (P, Hkv, page, hd) physical page pool
+    v_pages: Array,  # (P, Hkv, page, hd)
     page_table: Array,  # (S, pages_per_slot) int32 — logical -> physical
     lengths: Array,  # (S,) int32 — valid tokens per slot INCLUDING current
     window: int = -1,  # model convention: -1/GLOBAL = unbounded causal

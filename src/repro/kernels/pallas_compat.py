@@ -1,9 +1,4 @@
-"""Version-compat shims + shared defaults for the Pallas TPU API.
-
-``pltpu.CompilerParams`` was renamed across JAX releases (older releases
-expose ``TPUCompilerParams``; newer ones ``CompilerParams``). Every kernel
-imports the name from here so the repo tracks whichever the installed JAX
-provides.
+"""Shared interpret-mode policy for the Pallas TPU kernels.
 
 ``interpret_default`` is the single definition of the kernel families'
 interpret-mode fallback: run the real Mosaic lowering on TPU, the Pallas
@@ -14,11 +9,6 @@ through here so the TPU-detection logic cannot drift between families.
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def interpret_default(interpret: bool | None = None) -> bool:
@@ -29,4 +19,4 @@ def interpret_default(interpret: bool | None = None) -> bool:
     return bool(interpret)
 
 
-__all__ = ["CompilerParams", "interpret_default"]
+__all__ = ["interpret_default"]

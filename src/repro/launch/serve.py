@@ -5,14 +5,18 @@
 
 ``--scale tiny`` runs the reduced config on one CPU device. ``--scale
 full`` is the distribution-aware path: it builds the ``repro.dist`` mesh
-plan, shards params (no FSDP on the decode path), batch and KV cache via
-``ShardingRules`` — batch-parallel when the batch divides the data axes,
-sequence-parallel otherwise (the long-context fallback) — and reports the
-decode step's collectives via ``analyze_hlo``. On a TPU pod it uses the
-production mesh; on CPU back it with fake devices:
+plan for the device pool it runs on, shards params (no FSDP on the decode
+path), batch and KV cache via ``ShardingRules`` — batch-parallel when the
+batch divides the data axes, sequence-parallel otherwise (the
+long-context fallback) — and reports the decode step's collectives via
+``analyze_hlo``. A 256-chip pod gets the production mesh, any other pool
+a host plan; ``--layers`` cuts the depth in whole periods of the layer
+pattern. On CPU back it with fake devices:
 
     python -m repro.launch.serve --scale full --devices 8 --reduced \
         --batch 8 --prompt-len 32 --gen 8
+    python -m repro.launch.serve --scale full --arch hymba-1.5b \
+        --engine continuous --attn paged --slots 8 --requests 16  # one chip
 
 Engines:
 
@@ -37,7 +41,7 @@ import os
 import time
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--scale", default="tiny", choices=["tiny", "full"])
@@ -54,6 +58,10 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true",
                     help="with --scale full: reduced config on the real "
                          "mesh plan (CPU-executable sharded decode)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="with --scale full: cut the published depth to N "
+                         "layers (whole periods of the layer pattern; "
+                         "widths unchanged). 0 = published depth")
     # Continuous-batching knobs (--engine continuous).
     ap.add_argument("--requests", type=int, default=16,
                     help="trace length for --engine continuous")
@@ -72,8 +80,11 @@ def main(argv=None):
     ap.add_argument("--track", default=None,
                     help="tracker spec, e.g. jsonl:/tmp/serve.jsonl")
     ap.add_argument("--track-every", type=int, default=1)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.scale == "full" and args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
@@ -82,16 +93,14 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import get_config, get_reduced
+    from repro.launch import config_from_args
+    from repro.launch.compile_cache import use_persistent_cache
     from repro.models import Runtime, build_model
     from repro.models.config import Family
 
+    use_persistent_cache()
     full = args.scale == "full"
-    cfg = (
-        get_config(args.arch)
-        if full and not args.reduced
-        else get_reduced(args.arch, loss_chunk=0)
-    )
+    cfg = config_from_args(args)
     model = build_model(cfg)
     key = jax.random.PRNGKey(args.seed)
 
@@ -111,12 +120,13 @@ def main(argv=None):
         from repro.launch import mesh as mesh_mod
 
         pods = 2 if args.multi_pod else 1
-        if args.devices and args.devices != 256 * pods:
-            rules = make_rules(None, cfg, multi_pod=args.multi_pod,
-                               device_count=args.devices)
-        else:
+        pool = args.devices or jax.device_count()
+        if pool == mesh_mod.CHIPS_PER_POD * pods:
             pm = mesh_mod.make_production_mesh(multi_pod=args.multi_pod)
             rules = make_rules(pm, cfg, multi_pod=args.multi_pod)
+        else:
+            rules = make_rules(None, cfg, multi_pod=args.multi_pod,
+                               device_count=pool)
         mesh_shape = rules.mesh.shape
         runtime = Runtime(
             mesh=rules.mesh,
@@ -126,7 +136,8 @@ def main(argv=None):
             moe_impl="gshard" if cfg.num_experts else "dropless",
             moe_group_axes=rules.serve_batch_axes,
         )
-        print(f"[serve] mesh plan: {dict(mesh_shape)}")
+        print(f"[serve] mesh plan: {dict(mesh_shape)} "
+              f"layers={cfg.num_layers} params={model.param_count():,}")
 
     params = model.init(key)
     if rules is not None:
@@ -241,9 +252,11 @@ def _run_continuous(args, cfg, model, params, rules, runtime, tap):
         attn=args.attn,
         policy=args.policy,
     )
+    t0 = time.time()
     engine = ContinuousBatchingEngine(
         model, params, ecfg, runtime=runtime, tap=tap
     )
+    print(f"[serve] admit + decode compiled in {time.time() - t0:.1f}s")
     if rules is not None:
         from repro.dist import analyze_hlo
 

@@ -6,23 +6,29 @@ here). Wires: configs → model → mesh plan + sharding rules → FedFog round
         --rounds 100 --scale tiny --ckpt-dir /tmp/fedfog_ckpt
 
 ``--scale tiny`` substitutes the reduced config + a 1-device plan so the
-full driver logic (including checkpoint/restart) runs on this CPU
-container. ``--scale full`` is the distribution-aware path: it builds the
-mesh plan from ``repro.dist``, jits the round with in/out shardings from
-``ShardingRules`` and verifies via ``analyze_hlo`` that the compiled
-round contains exactly the paper's ONE inter-client all-reduce. On a TPU
-pod it uses the 256-chip production mesh; on CPU, back it with fake
-devices:
+full training loop (including checkpoint/restart) runs on a CPU host.
+``--scale full`` is the distribution-aware path: it builds the mesh plan
+from ``repro.dist`` for the device pool it runs on, jits the round with
+in/out shardings from ``ShardingRules`` and verifies via ``analyze_hlo``
+that the compiled round contains exactly the paper's ONE inter-client
+all-reduce. A 256-chip pod gets the production mesh; any other pool a
+client × zero host plan (one chip: 1 × 1, four chips: 2 × 2). ``--layers``
+cuts the depth, in whole periods of the layer pattern, for a pool that
+cannot hold every layer; widths are never cut. On CPU, back the plan with
+fake devices:
 
     python -m repro.launch.train --scale full --devices 256 --compile-only
     python -m repro.launch.train --scale full --devices 8 \
         --reduced --rounds 2          # actually executes sharded rounds
+    python -m repro.launch.train --scale full --arch rwkv6-1.6b \
+        --layers 5 --slots 2 --pallas-agg --rounds 3   # one TPU chip
 """
 from __future__ import annotations
 
 import argparse
 import os
 import time
+from typing import Any, NamedTuple
 
 
 def parse_args(argv=None):
@@ -88,6 +94,10 @@ def parse_args(argv=None):
     ap.add_argument("--reduced", action="store_true",
                     help="with --scale full: reduced config on the real "
                          "mesh plan (CPU-executable sharded rounds)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="with --scale full: cut the published depth to N "
+                         "layers (whole periods of the layer pattern; "
+                         "widths unchanged). 0 = published depth")
     ap.add_argument("--compile-only", action="store_true",
                     help="with --scale full: lower+compile the sharded "
                          "round, report collectives, skip execution")
@@ -119,7 +129,32 @@ def fault_config_from_args(args):
     )
 
 
-def main(argv=None):
+class TrainRun(NamedTuple):
+    """What :func:`main` ran: the final round state, one dict of host
+    metrics per round, and the model and FL config it built."""
+
+    state: Any
+    history: list
+    model: Any
+    fl_cfg: Any
+
+
+def init_state(model, fl_cfg, seed: int, sharding=None):
+    """The round's starting state for ``--seed``, made by one jitted
+    program, straight into ``sharding`` when one is given."""
+    import jax
+
+    from repro.fl import init_fl_state
+
+    return jax.jit(
+        lambda k: init_fl_state(model, fl_cfg, k), out_shardings=sharding
+    )(jax.random.PRNGKey(seed))
+
+
+def main(argv=None, devices=None):
+    """Run the training loop; returns a :class:`TrainRun` (None with
+    ``--compile-only``). ``devices`` restricts the ``--scale full`` plan
+    to that device list (default: every local device)."""
     args = parse_args(argv)
     if args.scale == "full" and args.devices:
         # Must precede the first jax backend init in this process.
@@ -130,8 +165,11 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
+
     from repro import checkpoint as ckpt
-    from repro.configs import get_config, get_reduced
     from repro.data.synthetic import (
         FedDataConfig,
         all_client_histograms,
@@ -145,15 +183,12 @@ def main(argv=None):
         step_telemetry,
     )
     from repro.fl import FLConfig, init_fl_state, make_round_fn
+    from repro.launch import config_from_args
     from repro.models import Runtime, build_model
     from repro.obs import tracker_from_spec
 
     full = args.scale == "full"
-    cfg = (
-        get_config(args.arch)
-        if full and not args.reduced
-        else get_reduced(args.arch, loss_chunk=0)
-    )
+    cfg = config_from_args(args)
     model = build_model(cfg)
 
     rules = None
@@ -162,18 +197,24 @@ def main(argv=None):
         from repro.launch import mesh as mesh_mod
 
         pods = 2 if args.multi_pod else 1
-        if args.devices and args.devices != 256 * pods:
-            # Scaled host plan (client × zero only) on N local devices.
-            rules = make_rules(
-                None, cfg, multi_pod=args.multi_pod,
-                device_count=args.devices,
-            )
-        else:
+        pool = args.devices or len(devices or jax.devices())
+        if pool == mesh_mod.CHIPS_PER_POD * pods and devices is None:
             pm = mesh_mod.make_production_mesh(multi_pod=args.multi_pod)
             rules = make_rules(pm, cfg, multi_pod=args.multi_pod)
-        args.slots = rules.plan.num_clients
+        else:
+            # Host plan (client × zero only) on the pool's devices.
+            rules = make_rules(
+                None, cfg, multi_pod=args.multi_pod, device_count=pool,
+                devices=devices,
+            )
+        # Slots fill the client ways: a multiple of them, each client
+        # shard vmapping its share of the slots.
+        ways = rules.client_ways
+        args.slots = ways * -(-args.slots // ways)
         args.clients = max(args.clients, 2 * args.slots)
-        print(f"[train] mesh plan: {dict(rules.mesh.shape)}")
+        print(f"[train] mesh plan: {dict(rules.mesh.shape)} "
+              f"slots={args.slots} layers={cfg.num_layers} "
+              f"params={model.param_count():,}")
 
     fl_cfg = FLConfig(
         num_clients=args.clients,
@@ -214,8 +255,14 @@ def main(argv=None):
             donate_argnums=(0,),
         )
 
-    key = jax.random.PRNGKey(args.seed)
-    state = init_fl_state(model, fl_cfg, key)
+    if rules is not None:
+        # Straight into the round's layout: a state made on the first
+        # device would stay there beside its sharded copy through round 0
+        # and take the memory the round needs.
+        state = init_state(model, fl_cfg, args.seed,
+                           round_fn.input_shardings[0][0])
+    else:
+        state = init_fl_state(model, fl_cfg, jax.random.PRNGKey(args.seed))
     start_round = 0
     checkpointer = None
     if args.ckpt_dir:
@@ -230,11 +277,11 @@ def main(argv=None):
     data_key = jax.random.PRNGKey(args.seed + 1)
     tracker = tracker_from_spec(args.track)
     with tracker:
-        state = _train_loop(
+        state, history = _train_loop(
             args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
             profiles, sizes, start_round, checkpointer, tracker,
         )
-    return state
+    return TrainRun(state, history, model, fl_cfg)
 
 
 def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
@@ -246,6 +293,7 @@ def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
     from repro.data.telemetry import step_telemetry
 
     data_key = jax.random.PRNGKey(args.seed + 1)
+    history = []
     for r in range(start_round, args.rounds):
         t0 = time.time()
         data_key, kb = jax.random.split(data_key)
@@ -271,6 +319,7 @@ def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
             ),
         }
         state, metrics = round_fn(state, batch)
+        history.append({k: float(v) for k, v in metrics.items()})
         sel = metrics["num_selected"]
         if r % max(args.track_every, 1) == 0:
             tracker.log(
@@ -312,10 +361,9 @@ def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
     tracker.log_summary(
         {"arch": args.arch, "scale": args.scale,
          "rounds": args.rounds - start_round,
-         "final_loss": float(metrics["loss"]) if args.rounds > start_round
-         else 0.0}
+         "final_loss": history[-1]["loss"] if history else 0.0}
     )
-    return state
+    return state, history
 
 
 def _sharded_round_fn(args, cfg, model, fl_cfg, rules, flops_round):
@@ -376,6 +424,13 @@ def _sharded_round_fn(args, cfg, model, fl_cfg, rules, flops_round):
     t0 = time.time()
     compiled = jitted.lower(state_abs, batch_abs).compile()
     print(f"[train] sharded round compiled in {time.time() - t0:.1f}s")
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        print(f"[train] device memory per chip: "
+              f"args={mem.argument_size_in_bytes / 1e9:.2f} GB "
+              f"temp={mem.temp_size_in_bytes / 1e9:.2f} GB "
+              f"out={mem.output_size_in_bytes / 1e9:.2f} GB "
+              f"alias={mem.alias_size_in_bytes / 1e9:.2f} GB")
 
     hlo = analyze_hlo(compiled.as_text())
     stats = hlo.collectives

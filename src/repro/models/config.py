@@ -142,6 +142,31 @@ class ModelConfig:
         pat = self.window_pattern
         return tuple(pat[i % len(pat)] for i in range(self.num_layers))
 
+    def layer_period(self) -> int:
+        """Smallest number of layers whose window pattern, repeated a
+        whole number of times, gives the model's depth (1 for a uniform
+        stack, the whole depth for hymba's first/middle/last global
+        layers)."""
+        windows = self.layer_windows()
+        n = len(windows)
+        return next(
+            p for p in range(1, n + 1)
+            if n % p == 0 and windows == windows[:p] * (n // p)
+        )
+
+    def with_depth(self, num_layers: int) -> "ModelConfig":
+        """The same model cut to ``num_layers`` layers: every width kept,
+        depth cut only in whole periods of the layer pattern, so each
+        kind of layer stays present in its published ratio."""
+        period = self.layer_period()
+        if not 1 <= num_layers <= self.num_layers or num_layers % period:
+            raise ValueError(
+                f"{self.name}: a depth of {num_layers} layers is not a whole "
+                f"number of {period}-layer pattern periods within its "
+                f"{self.num_layers} layers"
+            )
+        return dataclasses.replace(self, num_layers=num_layers)
+
     def is_subquadratic(self) -> bool:
         """True if decode-state size is bounded (SWA/SSM/linear-attention),
         i.e. the arch qualifies for the long_500k cell (DESIGN.md §5)."""

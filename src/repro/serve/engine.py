@@ -286,10 +286,14 @@ class ContinuousBatchingEngine:
                     continue  # loop condition decides termination
                 vclock = max(vclock, t)
                 continue
-            # 4. One batched decode step — THE compiled executable.
+            # 4. One batched decode step — THE compiled executable. The
+            # slot arrays are mutated in place right after this async
+            # dispatch, and the CPU client may alias numpy buffers
+            # instead of copying them, so the step gets snapshots.
             pool, tokens, out_buf = self._decode(
                 self.params, pool, tokens, out_buf,
-                page_table, positions, active, out_req, out_idx,
+                *(a.copy() for a in (page_table, positions, active,
+                                     out_req, out_idx)),
             )
             n_active = int(active.sum())
             decode_steps += 1

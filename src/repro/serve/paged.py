@@ -124,7 +124,8 @@ def init_pool(
     cfg: ModelConfig, plan: PagePlan, slots: int, num_pages: int, dtype=None
 ):
     """Fixed-shape device state. Physical page 0 is the trash page, so the
-    k/v pools carry ``num_pages + 1`` physical rows."""
+    k/v pools carry ``num_pages + 1`` physical rows, each laid out
+    ``(Hkv, page, hd)`` as the paged kernel reads them."""
     check_family(cfg)
     if cfg.family is Family.SSM:
         pool = rwkv6.init_cache(cfg, slots, 0)
@@ -133,8 +134,8 @@ def init_pool(
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     L, Hkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
     pool = {
-        "k": jnp.zeros((L, num_pages + 1, plan.page_size, Hkv, hd), dtype),
-        "v": jnp.zeros((L, num_pages + 1, plan.page_size, Hkv, hd), dtype),
+        "k": jnp.zeros((L, num_pages + 1, Hkv, plan.page_size, hd), dtype),
+        "v": jnp.zeros((L, num_pages + 1, Hkv, plan.page_size, hd), dtype),
     }
     if cfg.family is Family.HYBRID:
         pool["ssm_state"] = jnp.zeros(
@@ -182,9 +183,13 @@ def make_admit_fn(model: Model, plan: PagePlan, runtime: Runtime = Runtime()):
         else:
             L = cfg.num_layers
             shape = (L, plan.prompt_pages, plan.page_size) + cache["k"].shape[3:]
+
+            def as_pages(c):  # (L, prefill_len, Hkv, hd) -> (L, n, Hkv, page, hd)
+                return jnp.swapaxes(c[:, 0].reshape(shape), 2, 3)
+
             pool = dict(pool)
-            pool["k"] = pool["k"].at[:, pages].set(cache["k"][:, 0].reshape(shape))
-            pool["v"] = pool["v"].at[:, pages].set(cache["v"][:, 0].reshape(shape))
+            pool["k"] = pool["k"].at[:, pages].set(as_pages(cache["k"]))
+            pool["v"] = pool["v"].at[:, pages].set(as_pages(cache["v"]))
             if cfg.family is Family.HYBRID:
                 pool["ssm_state"] = (
                     pool["ssm_state"].at[:, slot].set(cache["ssm_state"][:, 0])
@@ -236,8 +241,8 @@ def _paged_transformer_step(
         w_i, th_i = static_layer_meta(cfg, i)
         q = tf.apply_rope(q, pos2, th_i)
         k = tf.apply_rope(k, pos2, th_i)
-        k_pool = k_pool.at[i, tgt, off].set(k[:, 0])
-        v_pool = v_pool.at[i, tgt, off].set(v[:, 0])
+        k_pool = k_pool.at[i, tgt, :, off].set(k[:, 0])
+        v_pool = v_pool.at[i, tgt, :, off].set(v[:, 0])
         if attn == "paged":
             lengths = jnp.where(active, positions + 1, 0)
             out = paged_attention(
@@ -269,6 +274,36 @@ def _paged_transformer_step(
     return logits, pool
 
 
+def make_logits_fn(
+    model: Model,
+    plan: PagePlan,
+    runtime: Runtime = Runtime(),
+    attn: str = "dense",
+    interpret: bool | None = None,
+):
+    """Returns ``logits_fn(params, pool, tokens, page_table, positions,
+    active) -> (logits (S, 1, V), pool)``: one batched decode step up to
+    the logits, before sampling — where the two attention modes are
+    compared."""
+    cfg = model.cfg
+    check_family(cfg)
+    if attn not in ATTN_MODES:
+        raise ValueError(f"attn must be one of {ATTN_MODES}, got {attn!r}")
+
+    def logits_fn(params, pool, tokens, page_table, positions, active):
+        if cfg.family is Family.SSM:
+            cache = dict(pool, pos=jnp.zeros((), jnp.int32))
+            logits, cache = rwkv6.decode_step(params, cfg, cache, tokens)
+            cache.pop("pos")
+            return logits, cache
+        return _paged_transformer_step(
+            params, cfg, plan, pool, tokens, page_table, positions,
+            active, runtime, attn, interpret,
+        )
+
+    return logits_fn
+
+
 def make_decode_fn(
     model: Model,
     plan: PagePlan,
@@ -283,23 +318,13 @@ def make_decode_fn(
     ``out_req``/``out_idx`` route each slot's new token into the device
     output buffer; the host passes the trash row for inactive slots.
     """
-    cfg = model.cfg
-    check_family(cfg)
-    if attn not in ATTN_MODES:
-        raise ValueError(f"attn must be one of {ATTN_MODES}, got {attn!r}")
+    logits_fn = make_logits_fn(model, plan, runtime, attn, interpret)
 
     def step(params, pool, tokens, out_buf, page_table, positions, active,
              out_req, out_idx):
-        if cfg.family is Family.SSM:
-            cache = dict(pool, pos=jnp.zeros((), jnp.int32))
-            logits, cache = rwkv6.decode_step(params, cfg, cache, tokens)
-            cache.pop("pos")
-            pool = cache
-        else:
-            logits, pool = _paged_transformer_step(
-                params, cfg, plan, pool, tokens, page_table, positions,
-                active, runtime, attn, interpret,
-            )
+        logits, pool = logits_fn(
+            params, pool, tokens, page_table, positions, active
+        )
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)  # (S,)
         tokens = nxt[:, None]
         out_buf = out_buf.at[out_req, out_idx].set(nxt)

@@ -271,39 +271,10 @@ _PROGRAM_CACHE_MAX = 64
 # back to a fresh compile that overwrites the entry.
 _DISK_CACHE_ENV = "REPRO_COMPILE_CACHE_DIR"
 _DISK_CACHE_VERSION = 1
-_XLA_CACHE_ENABLED = False
 
 
 def _disk_cache_dir() -> str | None:
     return os.environ.get(_DISK_CACHE_ENV) or None
-
-
-def _maybe_enable_xla_cache(path: str) -> None:
-    """Opportunistically point jax's own persistent compilation cache at
-    the same directory — it cannot skip tracing like the executable
-    serialization below, but it warms every OTHER jit in the process
-    (per-round loops, benchmark harness jits) where supported."""
-    global _XLA_CACHE_ENABLED
-    if _XLA_CACHE_ENABLED:
-        return
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _XLA_CACHE_ENABLED = True
-    except Exception:  # unsupported backend/version: purely best-effort
-        _XLA_CACHE_ENABLED = True  # don't retry every call
-
-
-def disable_xla_cache() -> None:
-    """Undo ``_maybe_enable_xla_cache`` — for callers (the benchmark
-    harness) that pointed the cache at a temp directory they are about
-    to delete and must not leak the global config to later workloads."""
-    global _XLA_CACHE_ENABLED
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
-    _XLA_CACHE_ENABLED = False
 
 
 def _disk_cache_path(cache_key) -> str | None:
@@ -575,8 +546,6 @@ def run_sweep(
             timings.setdefault(k, 0.0)
         for k in ("n_compiles", "cache_hits", "disk_hits", "n_groups"):
             timings.setdefault(k, 0)
-    if _disk_cache_dir() is not None:
-        _maybe_enable_xla_cache(_disk_cache_dir())
 
     n_seeds = int(seeds_arr.shape[0])
     seed_sharding = None

@@ -1,0 +1,109 @@
+"""Compile-only checks of the main-path Pallas kernels at real widths,
+against a described TPU v5e (``v5e:2x2``; no chip is attached).
+
+The TPU compiler refuses tilings and VMEM budgets that interpret mode
+accepts, so each kernel the chip runs is compiled here at the shapes the
+chip smoke run uses, and each compiled program must hold the kernel
+(``tpu_custom_call``). Nothing runs, so no result or time comes from
+these tests.
+
+The topology is described only inside the module fixture: loading the TPU
+compiler's library takes a process-wide lock, so it must not happen while
+any module is imported.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.delta_pipeline import delta_pipeline_apply
+from repro.kernels.paged_attention.ops import paged_attention
+from repro.models import build_model
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # no compiler logs on disk
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler in this environment
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # Programs compiled for a described chip can be written to the
+        # persistent cache but never read back: keep them out of it.
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_delta_pipeline_compiles_at_the_lm_round_shape(one_chip):
+    """FedAvgM gates at the (C, P) of the rwkv6-1.6b round one chip
+    holds: 2 slots, depth cut to 5 layers (545.8M parameters)."""
+    c = 2
+    p = build_model(get_config("rwkv6-1.6b").with_depth(5)).param_count()
+    f32 = jnp.float32
+    compiled = delta_pipeline_apply.lower(
+        _spec(one_chip, (c, p), f32), _spec(one_chip, (p,), f32),
+        _spec(one_chip, (c,), jnp.bool_), _spec(one_chip, (c,), f32),
+        1.0, momentum=_spec(one_chip, (p,), f32),
+        server_optimizer="fedavgm", interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("aggregator", ["median", "trimmed"])
+def test_robust_delta_pipeline_compiles(one_chip, aggregator):
+    """In-kernel bitonic selection over the simulator's 16-client cohort."""
+    c, p = 16, 1 << 20
+    f32 = jnp.float32
+    compiled = delta_pipeline_apply.lower(
+        _spec(one_chip, (c, p), f32), _spec(one_chip, (p,), f32),
+        _spec(one_chip, (c,), jnp.bool_), _spec(one_chip, (c,), f32),
+        aggregator=aggregator, interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize(
+    "window,dtype",
+    [(-1, jnp.bfloat16), (1024, jnp.bfloat16), (1024, jnp.float32)],
+    ids=["global", "window1024", "window1024-f32"],
+)
+def test_paged_attention_compiles_at_hymba_widths(one_chip, window, dtype):
+    """hymba-1.5b: 25 query heads over 5 KV heads of 64, page 16; the
+    serving smoke run's 8 slots of a 1536-token prompt plus 32 tokens."""
+    cfg = get_config("hymba-1.5b")
+    slots, page = 8, 16
+    n = -(-(1536 + 32) // page)
+    pool = (slots * n + 1, cfg.num_kv_heads, page, cfg.head_dim)
+    compiled = jax.jit(
+        lambda q, k, v, t, l: paged_attention(
+            q, k, v, t, l, window, interpret=False
+        )
+    ).lower(
+        _spec(one_chip, (slots, cfg.num_heads, cfg.head_dim), dtype),
+        _spec(one_chip, pool, dtype), _spec(one_chip, pool, dtype),
+        _spec(one_chip, (slots, n), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32),
+    ).compile()
+    _assert_kernel(compiled)
+

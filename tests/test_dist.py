@@ -105,6 +105,33 @@ def test_analyze_hlo_iota_transpose():
     assert op.groups == [[0, 2], [1, 3]]
 
 
+# TPU HLO: tiled layouts (":T(8,128)(2,1)") and a tuple-shaped
+# all-reduce, as the TPU compiler prints them.
+TPU_HLO = """\
+HloModule jit_round
+
+%add.2.clone (x: bf16[], y: bf16[]) -> bf16[] {
+  %x = bf16[] parameter(0)
+  %y = bf16[] parameter(1)
+  ROOT %add.3 = bf16[] add(bf16[] %x, bf16[] %y)
+}
+
+ENTRY %main.65_spmd (p0: bf16[1,4,128,2048], p1: bf16[1,4,128,2048]) -> (bf16[1,4,128,2048], bf16[1,4,128,2048]) {
+  %input_0.13 = bf16[1,4,128,2048]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %input_1.13 = bf16[1,4,128,2048]{3,2,1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %all-reduce.153 = (bf16[1,4,128,2048]{3,2,1,0:T(8,128)(2,1)}, bf16[1,4,128,2048]{3,2,1,0:T(8,128)(2,1)}) all-reduce(%input_0.13, %input_1.13), channel_id=225, replica_groups={{0,1},{2,3}}, use_global_device_ids=true, to_apply=%add.2.clone
+}
+"""
+
+
+def test_analyze_hlo_tpu_tiled_layouts():
+    a = analyze_hlo(TPU_HLO)
+    (op,) = a.collectives.ops
+    assert op.kind == "all-reduce"
+    assert op.bytes == 2 * (4 * 128 * 2048 * 2)  # both tuple elements
+    assert op.groups == [[0, 1], [2, 3]]
+
+
 def _fake_mesh(shape: dict):
     return types.SimpleNamespace(
         axis_names=tuple(shape), shape=dict(shape)
@@ -248,3 +275,66 @@ def test_rules_stacked_prepends_client_axis():
     )
     for s in jax.tree.flatten(specs, is_leaf=lambda x: isinstance(x, P))[0]:
         assert s[0] == "client"
+
+
+def test_host_plans_for_one_and_four_chips():
+    cfg = get_config("rwkv6-1.6b")
+    assert plan_for(cfg, device_count=1).shape == {
+        "client": 1, "zero": 1, "tp": 1, "sp": 1
+    }
+    assert plan_for(cfg, device_count=4).shape == {
+        "client": 2, "zero": 2, "tp": 1, "sp": 1
+    }
+
+
+def test_mesh_axes_are_auto():
+    """with_sharding_constraint accepts only Auto axes; jax.make_mesh
+    would default to Explicit ones."""
+    rules = make_rules(
+        None, get_reduced("llama3.2-1b"), device_count=1,
+        devices=jax.devices()[:1],
+    )
+    assert set(rules.mesh.axis_types) == {jax.sharding.AxisType.Auto}
+
+
+@pytest.mark.parametrize(
+    "arch,layers,ok",
+    [
+        ("rwkv6-1.6b", 5, True),  # uniform stack: any depth
+        ("gemma3-12b", 12, True),  # two 5-local:1-global periods
+        ("gemma3-12b", 10, False),  # not whole periods
+        ("hymba-1.5b", 32, True),  # global at first/middle/last layer
+        ("hymba-1.5b", 16, False),  # its pattern spans the whole depth
+        ("rwkv6-1.6b", 25, False),  # deeper than published
+    ],
+)
+def test_with_depth_cuts_whole_periods(arch, layers, ok):
+    cfg = get_config(arch)
+    if not ok:
+        with pytest.raises(ValueError):
+            cfg.with_depth(layers)
+        return
+    cut = cfg.with_depth(layers)
+    assert cut.num_layers == layers
+    assert (cut.d_model, cut.d_ff, cut.vocab_size, cut.num_heads) == (
+        cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.num_heads
+    )
+    assert cut.layer_windows() == cfg.layer_windows()[:layers]
+
+
+def test_peak_table_keyed_by_device_kind():
+    from repro.launch.mesh import peaks_for
+
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError):
+        peaks_for("cpu")
+
+
+def test_persistent_cache_leaves_env_dir_to_jax(monkeypatch):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.use_persistent_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == before

@@ -109,8 +109,8 @@ def test_paged_kernel_matches_dense_ref(case):
     num_pages = s * n + 1
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (s, hkv * g, hd), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (num_pages, page, hkv, hd), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (num_pages, page, hkv, hd), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (num_pages, hkv, page, hd), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (num_pages, hkv, page, hd), jnp.float32)
     table = (
         jax.random.permutation(ks[3], num_pages - 1)[: s * n] + 1
     ).reshape(s, n).astype(jnp.int32)
@@ -133,15 +133,16 @@ def test_paged_kernel_ignores_dead_pages():
     s, hkv, g, hd, page, n = 2, 2, 2, 64, 8, 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (s, hkv * g, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (s * n + 1, page, hkv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (s * n + 1, page, hkv, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (s * n + 1, hkv, page, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (s * n + 1, hkv, page, hd), jnp.float32)
     table = jnp.arange(1, s * n + 1, dtype=jnp.int32).reshape(s, n)
     lengths = jnp.array([5, page * n], jnp.int32)
     out = paged_attention(q, k, v, table, lengths, interpret=True)
     # Scribble over every position at/after each slot's length.
     mask = jnp.arange(page * n).reshape(n, page)[None] >= lengths[:, None, None]
-    k2 = k.at[table].set(jnp.where(mask[..., None, None], 1e4, k[table]))
-    v2 = v.at[table].set(jnp.where(mask[..., None, None], -1e4, v[table]))
+    mask = mask[:, :, None, :, None]  # -> (S, n, Hkv, page, hd) pool rows
+    k2 = k.at[table].set(jnp.where(mask, 1e4, k[table]))
+    v2 = v.at[table].set(jnp.where(mask, -1e4, v[table]))
     out2 = paged_attention(q, k2, v2, table, lengths, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 

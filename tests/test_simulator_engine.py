@@ -213,6 +213,19 @@ def test_aot_scanned_matches_run_scanned():
             )
 
 
+@pytest.mark.parametrize("population", [None, 32])
+def test_scan_carry_leaves_own_their_buffers(population):
+    """The scanned engine donates params/scheduler/telemetry on an
+    accelerator; one buffer behind two leaves cannot be donated twice
+    (on a TPU the first scanned run fails), so every leaf owns its own."""
+    import jax
+
+    sim = FedFogSimulator(_cfg(population=population))
+    leaves = jax.tree.leaves((sim.params, sim.sched_state, sim.telemetry))
+    ptrs = [x.unsafe_buffer_pointer() for x in leaves]
+    assert len(set(ptrs)) == len(ptrs)
+
+
 def test_sweep_signature_aggregator_structural_trim_lifted():
     """Compile-cache keys must distinguish the kernel gate STRUCTURALLY:
     ``aggregator`` and ``use_pallas_agg`` each open a new compile group,
