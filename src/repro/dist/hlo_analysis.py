@@ -10,6 +10,17 @@ round contains exactly the paper's ONE inter-client all-reduce.
 Collectives inside while-loop bodies are counted ONCE (static texts carry
 no trip counts); such ops are surfaced in ``trip_count_warnings`` so the
 per-round byte totals are read with the right caveat.
+
+The same pass maps each instruction to the innermost ``fedfog.*`` scope
+(``jax.named_scope``) in its ``metadata={op_name=...}``: ``phases``. An
+instruction that the compiler made without metadata (copies, converts,
+async starts and dones, wrapped ops) takes, in this order, the scope of
+the computation it calls (its root's, else its first scoped
+instruction's), of its first scoped operand, or of the instruction that
+calls its own computation (a ``while`` body from its ``while``). XLA keeps
+scopes in metadata only, and JAX's persistent cache leaves metadata out
+of its key unless told otherwise, so an executable loaded from a cache
+entry written by unscoped code maps nothing.
 """
 from __future__ import annotations
 
@@ -50,6 +61,13 @@ _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?"
 )
 _SRC_TGT_RE = re.compile(r"source_target_pairs=\{([\d,{} ]*)\}")
+# Greedy: the last (innermost) scope of the op_name path.
+_PHASE_RE = re.compile(r'op_name="[^"]*(fedfog\.\w+)')
+_CALLEE_RE = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+_NAME_RE = re.compile(r"%?([\w.\-]+)")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")  # e.g. /*index=5*/ in long lists
+_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
 
 
 def _shape_bytes(type_str: str) -> float:
@@ -145,6 +163,59 @@ class HLOAnalysis:
     hbm_bytes_in: float
     hbm_bytes_out: float
     num_instructions: int
+    module: str = ""
+    # instruction -> innermost ``fedfog.*`` scope (scoped instructions only)
+    phases: dict[str, str] = dataclasses.field(default_factory=dict)
+    # instruction -> "TYPE opcode(operand,...)": its result type, opcode
+    # and operand names, which a device trace's event name prints too
+    # (scoped instructions only)
+    heads: dict[str, str] = dataclasses.field(default_factory=dict)
+
+
+class _PhaseMap:
+    """``HLOAnalysis.phases``, built instruction by instruction during the
+    one walk over the text, in which a computation's instructions come
+    after those of the computations it calls."""
+
+    def __init__(self):
+        self.phases: dict[str, str] = {}
+        self.operands: dict[str, list[str]] = {}
+        self.comp_phase: dict[str, str] = {}  # its ROOT's, else its first
+        self.caller: dict[str, str] = {}  # computation -> calling instruction
+        self.unscoped: dict[str, list[str]] = {}  # computation -> names
+
+    def add(self, comp: str, name: str, line: str, after_opcode: str):
+        callees = _CALLEE_RE.findall(line) + [
+            n for g in _BRANCHES_RE.findall(line) for n in _NAME_RE.findall(g)
+        ]
+        for c in callees:
+            self.caller.setdefault(c, name)
+        # Compiled text prints operands untyped: the first ")" ends them.
+        operands = self.operands[name] = _NAME_RE.findall(
+            _COMMENT_RE.sub("", after_opcode.split(")", 1)[0]))
+        pm = _PHASE_RE.search(line)
+        scope = pm.group(1) if pm else next(
+            (self.comp_phase[c] for c in callees if c in self.comp_phase),
+            None)
+        if scope is None:
+            scope = next(
+                (self.phases[o] for o in operands if o in self.phases), None)
+        if scope is None:
+            self.unscoped.setdefault(comp, []).append(name)
+            return
+        self.phases[name] = scope
+        if comp not in self.comp_phase or line.lstrip().startswith("ROOT"):
+            self.comp_phase[comp] = scope
+
+    def finish(self) -> dict[str, str]:
+        # Callers follow the computations they call: latest first, an
+        # outer caller's scope is settled before its callees take it.
+        for comp in reversed(list(self.unscoped)):
+            scope = self.phases.get(self.caller.get(comp, ""))
+            if scope is not None:
+                for name in self.unscoped[comp]:
+                    self.phases[name] = scope
+        return self.phases
 
 
 def analyze_hlo(hlo_text: str) -> HLOAnalysis:
@@ -153,6 +224,8 @@ def analyze_hlo(hlo_text: str) -> HLOAnalysis:
     instrs: list[tuple[str, str, str, str, str]] = []  # comp, name, type, op, line
     comp = ""
     loop_bodies: set[str] = set()
+    phases = _PhaseMap()
+    mm = _MODULE_RE.match(hlo_text)
 
     for raw in hlo_text.splitlines():
         line = raw.rstrip()
@@ -173,6 +246,7 @@ def analyze_hlo(hlo_text: str) -> HLOAnalysis:
         name, type_str, opcode = im.groups()
         shapes[name] = type_str
         instrs.append((comp, name, type_str, opcode, line))
+        phases.add(comp, name, line, line[im.end():])
         if opcode == "while":
             bm = re.search(r"body=%?([\w.\-]+)", line)
             if bm:
@@ -235,6 +309,9 @@ def analyze_hlo(hlo_text: str) -> HLOAnalysis:
         if comp == entry_comp and line.lstrip().startswith("ROOT"):
             entry_out = _shape_bytes(type_str)
 
+    phase_of = phases.finish()
+    heads = {n: f"{shapes[n]} {op}({','.join(phases.operands[n])})"
+             for _, n, _, op, _ in instrs if n in phase_of}
     return HLOAnalysis(
         collectives=CollectiveStats(ops=tuple(ops)),
         dot_flops=dot_flops,
@@ -242,6 +319,9 @@ def analyze_hlo(hlo_text: str) -> HLOAnalysis:
         hbm_bytes_in=entry_params,
         hbm_bytes_out=entry_out,
         num_instructions=len(instrs),
+        module=mm.group(1) if mm else "",
+        phases=phase_of,
+        heads=heads,
     )
 
 

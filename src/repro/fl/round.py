@@ -15,6 +15,12 @@
 `make_round_fn` returns `round_fn(state, batch) -> (state, metrics)` ready
 for jax.jit with the shardings from dist/sharding.py. Shape-static
 throughout: masks, not dynamic sets.
+
+The round's stages run under three ``jax.named_scope``s, which tag every
+compiled instruction's ``op_name`` metadata and change nothing else:
+``fedfog.schedule`` (stage 1), ``fedfog.local_train`` (stage 2) and
+``fedfog.server`` (stages 3 to 6). ``dist.hlo_analysis`` maps the compiled
+round's instructions to them (``HLOAnalysis.phases``).
 """
 from __future__ import annotations
 
@@ -208,159 +214,184 @@ def make_round_fn(
     def round_fn(state: FLState, batch) -> tuple[FLState, dict]:
         from repro.core.types import ClientTelemetry
 
-        rng, k_sched, k_attack, k_dp, k_mal = jax.random.split(state.rng, 5)
+        with jax.named_scope("fedfog.schedule"):
+            rng, k_sched, k_attack, k_dp, k_mal = jax.random.split(state.rng, 5)
 
-        # ---- 1. schedule over the N-client registry (Eqs. 1/2/3/7) ----- #
-        telemetry = ClientTelemetry(
-            cpu=batch["telemetry_cpu"],
-            mem=batch["telemetry_mem"],
-            batt=batch["telemetry_batt"],
-            energy=batch["telemetry_energy"],
-        )
-        if pop_mode:
-            # Sample the round's scheduling window from the (M,) registry
-            # (fold_in key 7 — disjoint from the 5-way round split) and
-            # gather its scheduler rows; the batch's telemetry/hist rows
-            # are window-positional (the caller feeds N rows for the
-            # window, not the whole population).
-            window_ids = fog_mod.stratified_cohort(
-                jax.random.fold_in(state.rng, 7),
-                fl_cfg.population, fl_cfg.num_clients,
+            # ---- 1. schedule over the N-client registry (Eqs. 1/2/3/7) ---- #
+            telemetry = ClientTelemetry(
+                cpu=batch["telemetry_cpu"],
+                mem=batch["telemetry_mem"],
+                batt=batch["telemetry_batt"],
+                energy=batch["telemetry_energy"],
             )
-            sched_view = fog_mod.gather_sched_rows(state.sched, window_ids)
-        else:
-            window_ids = None
-            sched_view = state.sched
-        decision = schedule_round(
-            sched_view, telemetry, batch["hist"], fl_cfg.scheduler
-        )
-        slot_ids, slot_mask = _slot_assignment(decision, fl_cfg, k_sched)
-        slot_sizes = batch["slot_data_sizes"]
-
-        # ---- 2. local training: C slots × E local steps --------------- #
-        def to_slots(x):
-            return x.reshape((c, x.shape[0] // c) + x.shape[1:])
-
-        model_batch = constrain_batch(
-            {
-                k: to_slots(v)
-                for k, v in batch.items()
-                if k in ("tokens", "patch_embeds", "frames")
-            }
-        )
-        if attack.kind == "label_flip":
-            n_mal = int(round(attack.fraction * c))
-            malicious = jnp.arange(c) < n_mal
-            malicious = jax.random.permutation(k_mal, malicious)
-            model_batch["tokens"] = attacks_mod.flip_labels(
-                model_batch["tokens"], malicious, model.cfg.vocab_size
+            if pop_mode:
+                # Sample the round's scheduling window from the (M,) registry
+                # (fold_in key 7 — disjoint from the 5-way round split) and
+                # gather its scheduler rows; the batch's telemetry/hist rows
+                # are window-positional (the caller feeds N rows for the
+                # window, not the whole population).
+                window_ids = fog_mod.stratified_cohort(
+                    jax.random.fold_in(state.rng, 7),
+                    fl_cfg.population, fl_cfg.num_clients,
+                )
+                sched_view = fog_mod.gather_sched_rows(state.sched, window_ids)
+            else:
+                window_ids = None
+                sched_view = state.sched
+            decision = schedule_round(
+                sched_view, telemetry, batch["hist"], fl_cfg.scheduler
             )
-        elif attack.kind != "none":
-            n_mal = int(round(attack.fraction * c))
-            malicious = jax.random.permutation(
-                k_mal, jnp.arange(c) < n_mal
-            )
-        else:
-            malicious = jnp.zeros((c,), bool)
+            slot_ids, slot_mask = _slot_assignment(decision, fl_cfg, k_sched)
+            slot_sizes = batch["slot_data_sizes"]
 
-        params0 = state.params
-        params_stacked = constrain_stacked(
-            jax.tree.map(
-                lambda p: jnp.broadcast_to(p[None], (c,) + p.shape), params0
-            )
-        )
-        inner_state = init_inner(params_stacked)
-        inner_state = inner_state._replace(
-            mu=constrain_opt_tree(inner_state.mu),
-            nu=None if inner_state.nu is None else constrain_opt_tree(inner_state.nu),
-        )
+        with jax.named_scope("fedfog.local_train"):
+            # ---- 2. local training: C slots × E local steps --------------- #
+            def to_slots(x):
+                return x.reshape((c, x.shape[0] // c) + x.shape[1:])
 
-        grad_fn = jax.vmap(jax.value_and_grad(per_slot_loss))
-
-        if fl_cfg.microbatch > 1:
-            # Gradient accumulation: scan over micro-splits of each slot's
-            # batch, accumulating fp32 grads. Bounds live activations to one
-            # microbatch's worth — the decisive train-memory knob at 14B+.
-            mb = fl_cfg.microbatch
-
-            def grad_fn(params_s, batch_s):  # noqa: F811
-                micro = {
-                    k: jnp.moveaxis(
-                        v.reshape((v.shape[0], mb, v.shape[1] // mb) + v.shape[2:]),
-                        1, 0,
-                    )
-                    for k, v in batch_s.items()
+            model_batch = constrain_batch(
+                {
+                    k: to_slots(v)
+                    for k, v in batch.items()
+                    if k in ("tokens", "patch_embeds", "frames")
                 }
-
-                def acc_step(carry, mbatch):
-                    g_acc, l_acc = carry
-                    loss, g = jax.vmap(jax.value_and_grad(per_slot_loss))(
-                        params_s, mbatch
-                    )
-                    g_acc = jax.tree.map(
-                        lambda a, b: a + b.astype(jnp.float32), g_acc, g
-                    )
-                    return (g_acc, l_acc + jnp.mean(loss)), None
-
-                g0 = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params_s
-                )
-                (g, l), _ = jax.lax.scan(acc_step, (g0, jnp.zeros(())), micro)
-                g = jax.tree.map(lambda a: (a / mb), g)
-                return l / mb, g
-
-        if fl_cfg.local_steps == 1:
-            loss, grads = grad_fn(params_stacked, model_batch)
-            updates, inner_state2 = update_inner(grads, inner_state, params_stacked)
-            params_stacked = apply_updates(params_stacked, updates)
-            mean_loss = jnp.mean(loss)
-        else:
-            # Split each slot's batch into E microbatches along the batch dim.
-            e = fl_cfg.local_steps
-
-            def split_steps(x):  # (C, B_c, ...) -> (E, C, B_c/E, ...)
-                b_c = x.shape[1]
-                return jnp.moveaxis(
-                    x.reshape((c, e, b_c // e) + x.shape[2:]), 1, 0
-                )
-
-            micro = {k: split_steps(v) for k, v in model_batch.items()}
-
-            def one_step(carry, mb):
-                params_s, inner, _ = carry
-                loss, grads = grad_fn(params_s, mb)
-                updates, inner = update_inner(grads, inner, params_s)
-                params_s = apply_updates(params_s, updates)
-                return (params_s, inner, jnp.mean(loss)), None
-
-            (params_stacked, inner_state2, mean_loss), _ = jax.lax.scan(
-                one_step, (params_stacked, inner_state, jnp.zeros(())), micro
             )
-        del inner_state2
+            if attack.kind == "label_flip":
+                n_mal = int(round(attack.fraction * c))
+                malicious = jnp.arange(c) < n_mal
+                malicious = jax.random.permutation(k_mal, malicious)
+                model_batch["tokens"] = attacks_mod.flip_labels(
+                    model_batch["tokens"], malicious, model.cfg.vocab_size
+                )
+            elif attack.kind != "none":
+                n_mal = int(round(attack.fraction * c))
+                malicious = jax.random.permutation(
+                    k_mal, jnp.arange(c) < n_mal
+                )
+            else:
+                malicious = jnp.zeros((c,), bool)
 
-        # ---- 3. deltas: clip → attack → compress ----------------------- #
-        deltas = jax.tree.map(
-            lambda p, p0: (
-                p.astype(jnp.float32) - p0.astype(jnp.float32)[None]
-            ).astype(p.dtype),
-            params_stacked,
-            params0,
-        )
-        use_kernel = use_pallas or use_pallas_sharded
-        # Delta attacks land BETWEEN clip and compress, so when the
-        # kernel path is on those two stages split: reference clip +
-        # corrupt here, compression onward stays fused (the kernel then
-        # runs with clip_norm=0).
-        split_clip = use_kernel and attack.kind not in ("none", "label_flip")
-        if not use_kernel:
-            # Reference pipeline: one XLA pass per stage per leaf. On
-            # the fused path these stages all fold into the kernel call
-            # below.
-            if fl_cfg.clip_norm > 0:
-                deltas = jax.vmap(
-                    lambda d: clip_by_global_norm(d, fl_cfg.clip_norm)[0]
-                )(deltas)
-            if attack.kind not in ("none", "label_flip"):
+            params0 = state.params
+            params_stacked = constrain_stacked(
+                jax.tree.map(
+                    lambda p: jnp.broadcast_to(p[None], (c,) + p.shape), params0
+                )
+            )
+            inner_state = init_inner(params_stacked)
+            inner_state = inner_state._replace(
+                mu=constrain_opt_tree(inner_state.mu),
+                nu=None if inner_state.nu is None
+                else constrain_opt_tree(inner_state.nu),
+            )
+
+            grad_fn = jax.vmap(jax.value_and_grad(per_slot_loss))
+
+            if fl_cfg.microbatch > 1:
+                # Gradient accumulation: scan over micro-splits of each slot's
+                # batch, accumulating fp32 grads. Bounds live activations to one
+                # microbatch's worth — the decisive train-memory knob at 14B+.
+                mb = fl_cfg.microbatch
+
+                def grad_fn(params_s, batch_s):  # noqa: F811
+                    micro = {
+                        k: jnp.moveaxis(
+                            v.reshape(
+                                (v.shape[0], mb, v.shape[1] // mb)
+                                + v.shape[2:]
+                            ),
+                            1, 0,
+                        )
+                        for k, v in batch_s.items()
+                    }
+
+                    def acc_step(carry, mbatch):
+                        g_acc, l_acc = carry
+                        loss, g = jax.vmap(jax.value_and_grad(per_slot_loss))(
+                            params_s, mbatch
+                        )
+                        g_acc = jax.tree.map(
+                            lambda a, b: a + b.astype(jnp.float32), g_acc, g
+                        )
+                        return (g_acc, l_acc + jnp.mean(loss)), None
+
+                    g0 = jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params_s
+                    )
+                    (g, l), _ = jax.lax.scan(acc_step, (g0, jnp.zeros(())), micro)
+                    g = jax.tree.map(lambda a: (a / mb), g)
+                    return l / mb, g
+
+            if fl_cfg.local_steps == 1:
+                loss, grads = grad_fn(params_stacked, model_batch)
+                updates, inner_state2 = update_inner(
+                    grads, inner_state, params_stacked
+                )
+                params_stacked = apply_updates(params_stacked, updates)
+                mean_loss = jnp.mean(loss)
+            else:
+                # Split each slot's batch into E microbatches along the batch dim.
+                e = fl_cfg.local_steps
+
+                def split_steps(x):  # (C, B_c, ...) -> (E, C, B_c/E, ...)
+                    b_c = x.shape[1]
+                    return jnp.moveaxis(
+                        x.reshape((c, e, b_c // e) + x.shape[2:]), 1, 0
+                    )
+
+                micro = {k: split_steps(v) for k, v in model_batch.items()}
+
+                def one_step(carry, mb):
+                    params_s, inner, _ = carry
+                    loss, grads = grad_fn(params_s, mb)
+                    updates, inner = update_inner(grads, inner, params_s)
+                    params_s = apply_updates(params_s, updates)
+                    return (params_s, inner, jnp.mean(loss)), None
+
+                (params_stacked, inner_state2, mean_loss), _ = jax.lax.scan(
+                    one_step, (params_stacked, inner_state, jnp.zeros(())), micro
+                )
+            del inner_state2
+
+        with jax.named_scope("fedfog.server"):
+            # ---- 3. deltas: clip → attack → compress ---------------------- #
+            deltas = jax.tree.map(
+                lambda p, p0: (
+                    p.astype(jnp.float32) - p0.astype(jnp.float32)[None]
+                ).astype(p.dtype),
+                params_stacked,
+                params0,
+            )
+            use_kernel = use_pallas or use_pallas_sharded
+            # Delta attacks land BETWEEN clip and compress, so when the
+            # kernel path is on those two stages split: reference clip +
+            # corrupt here, compression onward stays fused (the kernel then
+            # runs with clip_norm=0).
+            split_clip = use_kernel and attack.kind not in ("none", "label_flip")
+            if not use_kernel:
+                # Reference pipeline: one XLA pass per stage per leaf. On
+                # the fused path these stages all fold into the kernel call
+                # below.
+                if fl_cfg.clip_norm > 0:
+                    deltas = jax.vmap(
+                        lambda d: clip_by_global_norm(d, fl_cfg.clip_norm)[0]
+                    )(deltas)
+                if attack.kind not in ("none", "label_flip"):
+                    deltas = attacks_mod.corrupt_deltas(
+                        deltas, malicious, attack.kind, k_attack,
+                        noise_scale=attack.noise_scale,
+                        replacement_scale=attack.replacement_scale,
+                    )
+                    slot_mask = attacks_mod.dropout_mask(
+                        slot_mask, malicious, attack.kind
+                    )
+                deltas = apply_compression(
+                    deltas, fl_cfg.compression, fl_cfg.topk_fraction
+                )
+            elif split_clip:
+                if fl_cfg.clip_norm > 0:
+                    deltas = jax.vmap(
+                        lambda d: clip_by_global_norm(d, fl_cfg.clip_norm)[0]
+                    )(deltas)
                 deltas = attacks_mod.corrupt_deltas(
                     deltas, malicious, attack.kind, k_attack,
                     noise_scale=attack.noise_scale,
@@ -369,259 +400,243 @@ def make_round_fn(
                 slot_mask = attacks_mod.dropout_mask(
                     slot_mask, malicious, attack.kind
                 )
-            deltas = apply_compression(
-                deltas, fl_cfg.compression, fl_cfg.topk_fraction
-            )
-        elif split_clip:
-            if fl_cfg.clip_norm > 0:
-                deltas = jax.vmap(
-                    lambda d: clip_by_global_norm(d, fl_cfg.clip_norm)[0]
-                )(deltas)
-            deltas = attacks_mod.corrupt_deltas(
-                deltas, malicious, attack.kind, k_attack,
-                noise_scale=attack.noise_scale,
-                replacement_scale=attack.replacement_scale,
-            )
-            slot_mask = attacks_mod.dropout_mask(
-                slot_mask, malicious, attack.kind
-            )
 
-        # ---- 3b. fault plan: who actually arrives (repro.sim.faults) --- #
-        # Slot-level serverless failure plan: retries with backoff, fog
-        # outages, deadline losses and the quorum decision, drawn from a
-        # key chain disjoint from the round's 5-way split (fold_in 11) so
-        # faulted runs replay deterministically per seed. The arrival
-        # mask replaces ``slot_mask`` BEFORE aggregation, so Eq. 6
-        # reweights over the arrivals only, on every aggregation path
-        # (reference, fog tier, fused kernel, sharded kernel).
-        fault_counters = faults_inject.zero_counters()
-        fault_skip = None
-        fault_round_ms = None
-        if faults_on:
-            fc = fl_cfg.faults
-            k_fplan, k_fnoise = jax.random.split(
-                jax.random.fold_in(state.rng, 11)
-            )
-            # Under mesh rules the plan must run as a replicated island:
-            # its (slots,) pred chains mix gathers from client-sharded
-            # arrays, and letting the SPMD partitioner reshard those mid-
-            # chain has been observed to MISCOMPILE (spmd_partitioner
-            # "involuntary full rematerialization" + wrong fail masks),
-            # breaking sharded-vs-plain fault replay. The arrays are
-            # tiny, so replication is free.
-            _rep = (
-                (lambda t: jax.tree.map(
-                    lambda x: jax.lax.with_sharding_constraint(
-                        x, NamedSharding(rules.mesh, P())
-                    ), t))
-                if rules is not None else (lambda t: t)
-            )
-            plan = faults_inject.plan_round(
-                fc, k_fplan, _rep(slot_mask),
-                _rep(~sched_view.warm[slot_ids]),
-                _rep(decision.delays_ms[slot_ids]),
-                fog_nodes=fl_cfg.fog_nodes,
-            )
-            plan = _rep(plan)
-            # Partitionable threefry for the payload noise: legacy
-            # (non-partitionable) threefry draws DIFFERENT bits under a
-            # multi-device lowering depending on the leaf's sharding
-            # spec, which would make a faulted sharded round diverge
-            # from its single-host replay by O(corrupt_scale). The
-            # context only rebinds the bit generator for these draws.
-            with jax.threefry_partitionable(True):
-                deltas = attacks_mod.corrupt_deltas(
-                    deltas, plan.corrupt, "noise", k_fnoise,
-                    noise_scale=fc.corrupt_scale,
+            # ---- 3b. fault plan: who actually arrives (repro.sim.faults) -- #
+            # Slot-level serverless failure plan: retries with backoff, fog
+            # outages, deadline losses and the quorum decision, drawn from a
+            # key chain disjoint from the round's 5-way split (fold_in 11) so
+            # faulted runs replay deterministically per seed. The arrival
+            # mask replaces ``slot_mask`` BEFORE aggregation, so Eq. 6
+            # reweights over the arrivals only, on every aggregation path
+            # (reference, fog tier, fused kernel, sharded kernel).
+            fault_counters = faults_inject.zero_counters()
+            fault_skip = None
+            fault_round_ms = None
+            if faults_on:
+                fc = fl_cfg.faults
+                k_fplan, k_fnoise = jax.random.split(
+                    jax.random.fold_in(state.rng, 11)
                 )
-            slot_mask = plan.arrived
-            fault_counters = plan.counters
-            fault_skip = plan.skip
-            fault_round_ms = plan.round_ms
+                # Under mesh rules the plan must run as a replicated island:
+                # its (slots,) pred chains mix gathers from client-sharded
+                # arrays, and letting the SPMD partitioner reshard those mid-
+                # chain has been observed to MISCOMPILE (spmd_partitioner
+                # "involuntary full rematerialization" + wrong fail masks),
+                # breaking sharded-vs-plain fault replay. The arrays are
+                # tiny, so replication is free.
+                _rep = (
+                    (lambda t: jax.tree.map(
+                        lambda x: jax.lax.with_sharding_constraint(
+                            x, NamedSharding(rules.mesh, P())
+                        ), t))
+                    if rules is not None else (lambda t: t)
+                )
+                plan = faults_inject.plan_round(
+                    fc, k_fplan, _rep(slot_mask),
+                    _rep(~sched_view.warm[slot_ids]),
+                    _rep(decision.delays_ms[slot_ids]),
+                    fog_nodes=fl_cfg.fog_nodes,
+                )
+                plan = _rep(plan)
+                # Partitionable threefry for the payload noise: legacy
+                # (non-partitionable) threefry draws DIFFERENT bits under a
+                # multi-device lowering depending on the leaf's sharding
+                # spec, which would make a faulted sharded round diverge
+                # from its single-host replay by O(corrupt_scale). The
+                # context only rebinds the bit generator for these draws.
+                with jax.threefry_partitionable(True):
+                    deltas = attacks_mod.corrupt_deltas(
+                        deltas, plan.corrupt, "noise", k_fnoise,
+                        noise_scale=fc.corrupt_scale,
+                    )
+                slot_mask = plan.arrived
+                fault_counters = plan.counters
+                fault_skip = plan.skip
+                fault_round_ms = plan.round_ms
 
-        # ---- 4+5. aggregate (Eq. 6) + server update -------------------- #
-        if use_kernel:
-            # Fused delta-pipeline kernel: clip, compression emulation,
-            # aggregation, DP noise, server momentum and the apply all
-            # happen in one pass over the fused (C, P) buffer — the
-            # memory-bound pipeline never re-reads the delta stack from
-            # HBM (clipping adds one norm-reduction pass). Under mesh
-            # rules the buffer is client-sharded and the sharded entry
-            # combines per-shard partial sums with ONE psum.
-            if use_pallas_sharded:
-                cat_d, _ = fuse_deltas(deltas, shard_p=False)
-            else:
-                cat_d, _ = fuse_clients(deltas)
-            base_flat, unfuse_vec = fuse_vector(params0)
-            seg = stacked_leaf_sizes(deltas)
-            noise = None
-            if fl_cfg.dp_sigma > 0:
-                noise = fused_gaussian_noise(
-                    k_dp,
-                    fl_cfg.dp_sigma * (fl_cfg.clip_norm or 1.0),
-                    seg,
-                    [x.shape for x in jax.tree.leaves(params0)],
+            # ---- 4+5. aggregate (Eq. 6) + server update ------------------- #
+            if use_kernel:
+                # Fused delta-pipeline kernel: clip, compression emulation,
+                # aggregation, DP noise, server momentum and the apply all
+                # happen in one pass over the fused (C, P) buffer — the
+                # memory-bound pipeline never re-reads the delta stack from
+                # HBM (clipping adds one norm-reduction pass). Under mesh
+                # rules the buffer is client-sharded and the sharded entry
+                # combines per-shard partial sums with ONE psum.
+                if use_pallas_sharded:
+                    cat_d, _ = fuse_deltas(deltas, shard_p=False)
+                else:
+                    cat_d, _ = fuse_clients(deltas)
+                base_flat, unfuse_vec = fuse_vector(params0)
+                seg = stacked_leaf_sizes(deltas)
+                noise = None
+                if fl_cfg.dp_sigma > 0:
+                    noise = fused_gaussian_noise(
+                        k_dp,
+                        fl_cfg.dp_sigma * (fl_cfg.clip_norm or 1.0),
+                        seg,
+                        [x.shape for x in jax.tree.leaves(params0)],
+                    )
+                mu_flat = unfuse_mu = None
+                if (
+                    fl_cfg.server_optimizer in ("fedavgm", "fedadam")
+                    and state.server_mu is not None
+                ):
+                    mu_flat, unfuse_mu = fuse_vector(state.server_mu)
+                kernel_clip = 0.0 if split_clip else fl_cfg.clip_norm
+                kw = dict(
+                    lr=fl_cfg.server_lr, dp_noise=noise, momentum=mu_flat,
+                    clip_norm=kernel_clip,
+                    compression=fl_cfg.compression,
+                    topk_fraction=fl_cfg.topk_fraction,
+                    seg_sizes=seg,
+                    server_optimizer=fl_cfg.server_optimizer,
+                    server_momentum=fl_cfg.server_momentum,
                 )
-            mu_flat = unfuse_mu = None
-            if (
-                fl_cfg.server_optimizer in ("fedavgm", "fedadam")
-                and state.server_mu is not None
-            ):
-                mu_flat, unfuse_mu = fuse_vector(state.server_mu)
-            kernel_clip = 0.0 if split_clip else fl_cfg.clip_norm
-            kw = dict(
-                lr=fl_cfg.server_lr, dp_noise=noise, momentum=mu_flat,
-                clip_norm=kernel_clip,
-                compression=fl_cfg.compression,
-                topk_fraction=fl_cfg.topk_fraction,
-                seg_sizes=seg,
-                server_optimizer=fl_cfg.server_optimizer,
-                server_momentum=fl_cfg.server_momentum,
-            )
-            if use_pallas_sharded:
-                outs = delta_pipeline_apply_sharded(
-                    cat_d, base_flat, slot_mask, slot_sizes,
-                    mesh=rules.mesh, client_axes=rules.plan.client_axes,
-                    fog_nodes=fl_cfg.fog_nodes,
-                    **kw,
-                )
-            elif fl_cfg.fog_nodes > 1:
-                # Single-host fog tier: one delta_pipeline_partial pass
-                # per fog's contiguous slot block + the shared cloud
-                # epilogue (fl/fog.py; fedavg-only, enforced by config).
-                outs = fog_mod.fog_pipeline_apply(
-                    cat_d, base_flat, slot_mask, slot_sizes,
-                    fog_nodes=fl_cfg.fog_nodes,
-                    **kw,
-                )
-            else:
-                outs = delta_pipeline_apply(
-                    cat_d, base_flat, slot_mask, slot_sizes,
-                    trim_fraction=fl_cfg.trim_fraction,
-                    aggregator=fl_cfg.aggregator,
-                    **kw,
-                )
-            if mu_flat is not None:
-                new_flat, new_mu_flat = outs
-                new_mu = unfuse_mu(new_mu_flat)
-            else:
-                new_flat, new_mu = outs, state.server_mu
-            new_params = unfuse_vec(new_flat)
-            new_count = state.server_count + 1
-        else:
-            # On the pod-scale path the leaves are fused into one (C, P)
-            # buffer first, so ALL the cross-client traffic of the round
-            # is a single all-reduce instead of one per parameter tensor.
-            agg_in, unfuse = (
-                fuse_deltas(deltas) if fuse_deltas is not None
-                else (deltas, None)
-            )
-            if fl_cfg.aggregator == "median":
-                agg = agg_mod.median_aggregate(agg_in, slot_mask)
-            elif fl_cfg.aggregator == "trimmed":
-                agg = agg_mod.trimmed_mean_aggregate(
-                    agg_in, slot_mask, fl_cfg.trim_fraction
-                )
-            elif fl_cfg.fog_nodes > 1:
-                # Hierarchical Eq. 6 on the reference path: fog partials
-                # → cloud combine (float-reassociated flat aggregate).
-                if unfuse is not None:
-                    agg = fog_mod.fog_aggregate(
-                        agg_in, slot_mask, slot_sizes, fl_cfg.fog_nodes
+                if use_pallas_sharded:
+                    outs = delta_pipeline_apply_sharded(
+                        cat_d, base_flat, slot_mask, slot_sizes,
+                        mesh=rules.mesh, client_axes=rules.plan.client_axes,
+                        fog_nodes=fl_cfg.fog_nodes,
+                        **kw,
+                    )
+                elif fl_cfg.fog_nodes > 1:
+                    # Single-host fog tier: one delta_pipeline_partial pass
+                    # per fog's contiguous slot block + the shared cloud
+                    # epilogue (fl/fog.py; fedavg-only, enforced by config).
+                    outs = fog_mod.fog_pipeline_apply(
+                        cat_d, base_flat, slot_mask, slot_sizes,
+                        fog_nodes=fl_cfg.fog_nodes,
+                        **kw,
                     )
                 else:
-                    agg = fog_mod.fog_aggregate_tree(
-                        agg_in, slot_mask, slot_sizes, fl_cfg.fog_nodes
+                    outs = delta_pipeline_apply(
+                        cat_d, base_flat, slot_mask, slot_sizes,
+                        trim_fraction=fl_cfg.trim_fraction,
+                        aggregator=fl_cfg.aggregator,
+                        **kw,
                     )
+                if mu_flat is not None:
+                    new_flat, new_mu_flat = outs
+                    new_mu = unfuse_mu(new_mu_flat)
+                else:
+                    new_flat, new_mu = outs, state.server_mu
+                new_params = unfuse_vec(new_flat)
+                new_count = state.server_count + 1
             else:
-                agg = agg_mod.fedavg_stacked(agg_in, slot_mask, slot_sizes)
-            if unfuse is not None:
-                agg = unfuse(agg)
-            if fl_cfg.dp_sigma > 0:
-                dp = privacy_mod.DPConfig(
-                    sigma=fl_cfg.dp_sigma,
-                    sensitivity=fl_cfg.clip_norm or 1.0,
+                # On the pod-scale path the leaves are fused into one (C, P)
+                # buffer first, so ALL the cross-client traffic of the round
+                # is a single all-reduce instead of one per parameter tensor.
+                agg_in, unfuse = (
+                    fuse_deltas(deltas) if fuse_deltas is not None
+                    else (deltas, None)
                 )
-                agg = privacy_mod.gaussian_mechanism(agg, k_dp, dp)
-            new_params, new_mu, new_count = _server_update(
-                fl_cfg, params0, agg, state.server_mu, state.server_count
-            )
-
-        if fault_skip is not None:
-            # Below-quorum round: the model (and server optimizer state)
-            # carries over bitwise — the attempted aggregate is discarded.
-            new_params = jax.tree.map(
-                lambda p, q: jnp.where(fault_skip, p, q), params0, new_params
-            )
-            if state.server_mu is not None:
-                new_mu = jax.tree.map(
-                    lambda p, q: jnp.where(fault_skip, p, q),
-                    state.server_mu, new_mu,
+                if fl_cfg.aggregator == "median":
+                    agg = agg_mod.median_aggregate(agg_in, slot_mask)
+                elif fl_cfg.aggregator == "trimmed":
+                    agg = agg_mod.trimmed_mean_aggregate(
+                        agg_in, slot_mask, fl_cfg.trim_fraction
+                    )
+                elif fl_cfg.fog_nodes > 1:
+                    # Hierarchical Eq. 6 on the reference path: fog partials
+                    # → cloud combine (float-reassociated flat aggregate).
+                    if unfuse is not None:
+                        agg = fog_mod.fog_aggregate(
+                            agg_in, slot_mask, slot_sizes, fl_cfg.fog_nodes
+                        )
+                    else:
+                        agg = fog_mod.fog_aggregate_tree(
+                            agg_in, slot_mask, slot_sizes, fl_cfg.fog_nodes
+                        )
+                else:
+                    agg = agg_mod.fedavg_stacked(agg_in, slot_mask, slot_sizes)
+                if unfuse is not None:
+                    agg = unfuse(agg)
+                if fl_cfg.dp_sigma > 0:
+                    dp = privacy_mod.DPConfig(
+                        sigma=fl_cfg.dp_sigma,
+                        sensitivity=fl_cfg.clip_norm or 1.0,
+                    )
+                    agg = privacy_mod.gaussian_mechanism(agg, k_dp, dp)
+                new_params, new_mu, new_count = _server_update(
+                    fl_cfg, params0, agg, state.server_mu, state.server_count
                 )
-            new_count = jnp.where(fault_skip, state.server_count, new_count)
 
-        # ---- 6. energy / cold-start / drift bookkeeping ---------------- #
-        # Per-LOGICAL-client energy: compute ∝ FLOPs for selected clients,
-        # uplink ∝ compressed delta bytes (§IV.F) — via the shared DES
-        # cost model (repro.sim.des).
-        tx_bytes = wire_bytes_per_param(
-            fl_cfg.compression, fl_cfg.topk_fraction
-        ) * float(model.param_count())
-        round_energy_j = cost_model.energy_j(
-            decision.selection.mask, sched_view.warm, flops_round, tx_bytes
-        )
-        if faults_on:
-            # Every launched attempt repays the slot's full per-round
-            # energy (a crashed function restarts from the global model);
-            # non-slot selected clients keep the 1× baseline.
-            round_energy_j = round_energy_j * (
-                jnp.ones_like(round_energy_j)
-                .at[slot_ids]
-                .set(jnp.maximum(plan.attempts, 1.0))
-            )
-        advanced = account_energy(
-            decision.new_state, round_energy_j, fl_cfg.scheduler
-        )
-        if pop_mode:
-            # Scatter the window's advanced rows back into the (M,)
-            # registry; unsampled clients stay frozen until next sampled.
-            new_sched = fog_mod.scatter_sched_rows(
-                state.sched, window_ids, advanced
-            )
-        else:
-            new_sched = advanced
-
-        new_state = FLState(
-            params=new_params,
-            server_mu=new_mu,
-            server_count=new_count,
-            sched=new_sched,
-            rng=rng,
-            step=state.step + 1,
-        )
-        metrics = {
-            "loss": mean_loss,
-            "num_selected": decision.selection.num_selected,
-            "slot_participation": jnp.sum(slot_mask.astype(jnp.int32)),
-            "cold_starts": decision.cold_starts,
-            # Synchronous round latency = slowest selected client (§III.H);
-            # under faults the retry/backoff chain (deadline-capped).
-            "round_latency_ms": (
-                fault_round_ms
-                if fault_round_ms is not None
-                else jnp.max(
-                    jnp.where(slot_mask, decision.delays_ms[slot_ids], 0.0)
+            if fault_skip is not None:
+                # Below-quorum round: the model (and server optimizer state)
+                # carries over bitwise — the attempted aggregate is discarded.
+                new_params = jax.tree.map(
+                    lambda p, q: jnp.where(fault_skip, p, q), params0, new_params
                 )
-            ),
-            "energy_j": jnp.sum(round_energy_j),
-            "mean_utility": jnp.mean(decision.selection.utility),
-            "mean_drift": jnp.mean(decision.selection.drift),
-            # Fault/recovery counters — structurally always present
-            # (zeros when the plan is off) so history schemas are stable
-            # across faulted and clean runs.
-            **fault_counters,
-        }
-        return new_state, metrics
+                if state.server_mu is not None:
+                    new_mu = jax.tree.map(
+                        lambda p, q: jnp.where(fault_skip, p, q),
+                        state.server_mu, new_mu,
+                    )
+                new_count = jnp.where(fault_skip, state.server_count, new_count)
+
+            # ---- 6. energy / cold-start / drift bookkeeping --------------- #
+            # Per-LOGICAL-client energy: compute ∝ FLOPs for selected clients,
+            # uplink ∝ compressed delta bytes (§IV.F) — via the shared DES
+            # cost model (repro.sim.des).
+            tx_bytes = wire_bytes_per_param(
+                fl_cfg.compression, fl_cfg.topk_fraction
+            ) * float(model.param_count())
+            round_energy_j = cost_model.energy_j(
+                decision.selection.mask, sched_view.warm, flops_round, tx_bytes
+            )
+            if faults_on:
+                # Every launched attempt repays the slot's full per-round
+                # energy (a crashed function restarts from the global model);
+                # non-slot selected clients keep the 1× baseline.
+                round_energy_j = round_energy_j * (
+                    jnp.ones_like(round_energy_j)
+                    .at[slot_ids]
+                    .set(jnp.maximum(plan.attempts, 1.0))
+                )
+            advanced = account_energy(
+                decision.new_state, round_energy_j, fl_cfg.scheduler
+            )
+            if pop_mode:
+                # Scatter the window's advanced rows back into the (M,)
+                # registry; unsampled clients stay frozen until next sampled.
+                new_sched = fog_mod.scatter_sched_rows(
+                    state.sched, window_ids, advanced
+                )
+            else:
+                new_sched = advanced
+
+            new_state = FLState(
+                params=new_params,
+                server_mu=new_mu,
+                server_count=new_count,
+                sched=new_sched,
+                rng=rng,
+                step=state.step + 1,
+            )
+            metrics = {
+                "loss": mean_loss,
+                "num_selected": decision.selection.num_selected,
+                "slot_participation": jnp.sum(slot_mask.astype(jnp.int32)),
+                "cold_starts": decision.cold_starts,
+                # Synchronous round latency = slowest selected client (§III.H);
+                # under faults the retry/backoff chain (deadline-capped).
+                "round_latency_ms": (
+                    fault_round_ms
+                    if fault_round_ms is not None
+                    else jnp.max(
+                        jnp.where(slot_mask, decision.delays_ms[slot_ids], 0.0)
+                    )
+                ),
+                "energy_j": jnp.sum(round_energy_j),
+                "mean_utility": jnp.mean(decision.selection.utility),
+                "mean_drift": jnp.mean(decision.selection.drift),
+                # Fault/recovery counters — structurally always present
+                # (zeros when the plan is off) so history schemas are stable
+                # across faulted and clean runs.
+                **fault_counters,
+            }
+            return new_state, metrics
 
     return round_fn
 

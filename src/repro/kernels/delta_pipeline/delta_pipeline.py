@@ -85,6 +85,7 @@ def delta_sq_norms(
         out_specs=pl.BlockSpec((c,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((c,), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="delta_sq_norms",
         interpret=interpret,
     )(updates)
 
@@ -448,6 +449,7 @@ def delta_pipeline_apply(
         out_specs=out_specs if has_mu else out_specs[0],
         out_shape=out_shape if has_mu else out_shape[0],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="delta_pipeline_apply",
         interpret=interpret,
     )(*inputs)
     if has_mu:
@@ -549,6 +551,7 @@ def delta_pipeline_partial(
         out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp_total,), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="delta_pipeline_partial",
         interpret=interpret,
     )(*inputs)
     return out[:d]

@@ -79,6 +79,7 @@ def fedavg_apply(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        name="fedavg_apply",
         interpret=interpret,
     )(wn, updates, base)
     return out[:d]

@@ -167,5 +167,6 @@ def flash_attention_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -172,6 +172,7 @@ def paged_attention_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="paged_attention",
         interpret=interpret,
     )(
         jnp.asarray(page_table, jnp.int32),

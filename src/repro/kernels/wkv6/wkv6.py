@@ -128,6 +128,7 @@ def wkv6_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="wkv6",
         interpret=interpret,
     )(r, k, v, w, u)
     return y, s_final

@@ -26,9 +26,11 @@ fake devices:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
-import time
 from typing import Any, NamedTuple
+
+from repro.obs import spans
 
 
 def parse_args(argv=None):
@@ -129,6 +131,26 @@ def fault_config_from_args(args):
     )
 
 
+@dataclasses.dataclass(frozen=True)
+class RoundProgram:
+    """The compiled round and what readers need of it: ``phases`` (HLO
+    instruction -> ``fedfog.*`` scope) and the HLO ``module`` name.
+    ``input_shardings`` is the executable's. A call dispatches one round
+    inside the ``fedfog.round`` span."""
+
+    compiled: Any
+    phases: dict = dataclasses.field(default_factory=dict, repr=False)
+    module: str = ""
+
+    @property
+    def input_shardings(self):
+        return self.compiled.input_shardings
+
+    def __call__(self, state, batch):
+        with spans.span("fedfog.round"):
+            return self.compiled(state, batch)
+
+
 class TrainRun(NamedTuple):
     """What :func:`main` ran: the final round state, one dict of host
     metrics per round, and the model and FL config it built."""
@@ -146,9 +168,10 @@ def init_state(model, fl_cfg, seed: int, sharding=None):
 
     from repro.fl import init_fl_state
 
-    return jax.jit(
-        lambda k: init_fl_state(model, fl_cfg, k), out_shardings=sharding
-    )(jax.random.PRNGKey(seed))
+    with spans.span("fedfog.setup.init_state"):
+        return jax.block_until_ready(jax.jit(
+            lambda k: init_fl_state(model, fl_cfg, k), out_shardings=sharding
+        )(jax.random.PRNGKey(seed)))
 
 
 def main(argv=None, devices=None):
@@ -245,7 +268,7 @@ def main(argv=None, devices=None):
         if args.compile_only:
             return None
     else:
-        round_fn = jax.jit(
+        round_fn = RoundProgram(jax.jit(
             make_round_fn(
                 model,
                 fl_cfg,
@@ -253,7 +276,7 @@ def main(argv=None, devices=None):
                 flops_per_client_round=flops_round,
             ),
             donate_argnums=(0,),
-        )
+        ))
 
     if rules is not None:
         # Straight into the round's layout: a state made on the first
@@ -295,53 +318,62 @@ def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
     data_key = jax.random.PRNGKey(args.seed + 1)
     history = []
     for r in range(start_round, args.rounds):
-        t0 = time.time()
-        data_key, kb = jax.random.split(data_key)
-        r_idx = jnp.asarray(r, jnp.int32)
-        # Occupants for this round: previous utility order isn't known
-        # host-side before the jit call, so the pipeline streams data for
-        # the scheduler's PREDICTED top slots (previous-round order); the
-        # round function re-ranks internally. Here: round-robin cohort.
-        slot_ids = (jnp.arange(fl_cfg.slots) + r * fl_cfg.slots) % args.clients
-        tokens = round_batch(
-            data_cfg, slot_ids, r_idx, kb,
-            args.batch_per_slot * args.local_steps, args.seq_len,
-        )
-        batch = {
-            "tokens": tokens,
-            "slot_data_sizes": sizes[slot_ids],
-            "telemetry_cpu": telemetry.cpu,
-            "telemetry_mem": telemetry.mem,
-            "telemetry_batt": telemetry.batt,
-            "telemetry_energy": telemetry.energy,
-            "hist": all_client_histograms(
-                data_cfg, args.clients, r_idx, fl_cfg.hist_bins
-            ),
-        }
+        with spans.span("fedfog.round.inputs") as inputs:
+            data_key, kb = jax.random.split(data_key)
+            r_idx = jnp.asarray(r, jnp.int32)
+            # Occupants for this round: previous utility order isn't known
+            # host-side before the jit call, so the pipeline streams data
+            # for the scheduler's PREDICTED top slots (previous-round
+            # order); the round function re-ranks internally. Here:
+            # round-robin cohort.
+            slot_ids = (
+                (jnp.arange(fl_cfg.slots) + r * fl_cfg.slots) % args.clients
+            )
+            tokens = round_batch(
+                data_cfg, slot_ids, r_idx, kb,
+                args.batch_per_slot * args.local_steps, args.seq_len,
+            )
+            batch = {
+                "tokens": tokens,
+                "slot_data_sizes": sizes[slot_ids],
+                "telemetry_cpu": telemetry.cpu,
+                "telemetry_mem": telemetry.mem,
+                "telemetry_batt": telemetry.batt,
+                "telemetry_energy": telemetry.energy,
+                "hist": all_client_histograms(
+                    data_cfg, args.clients, r_idx, fl_cfg.hist_bins
+                ),
+            }
         state, metrics = round_fn(state, batch)
+        # One device-to-host read of every metric, shared by the history,
+        # the tracker and the print.
+        with spans.span("fedfog.round.read") as read:
+            metrics = jax.device_get(metrics)
+        round_wall_s = (inputs.seconds + spans.stats()["fedfog.round"].last_s
+                        + read.seconds)
         history.append({k: float(v) for k, v in metrics.items()})
-        sel = metrics["num_selected"]
         if r % max(args.track_every, 1) == 0:
             tracker.log(
                 {"event": "round", "arch": args.arch, "scale": args.scale,
-                 **{k: v for k, v in metrics.items()},
-                 "round_wall_s": time.time() - t0},
+                 **metrics, "round_wall_s": round_wall_s},
                 step=r,
             )
-        data_key, kt = jax.random.split(data_key)
-        telemetry = step_telemetry(
-            tel_cfg,
-            telemetry,
-            jnp.zeros((args.clients,), bool)
-            .at[slot_ids]
-            .set(True),
-            jnp.zeros((args.clients,)),
-            profiles,
-            kt,
-        )
+        with spans.span("fedfog.round.telemetry") as tel:
+            data_key, kt = jax.random.split(data_key)
+            telemetry = step_telemetry(
+                tel_cfg,
+                telemetry,
+                jnp.zeros((args.clients,), bool)
+                .at[slot_ids]
+                .set(True),
+                jnp.zeros((args.clients,)),
+                profiles,
+                kt,
+            )
         print(
             f"[round {r:4d}] loss={float(metrics['loss']):.4f} "
-            f"selected={int(sel)} cold={int(metrics['cold_starts'])} "
+            f"selected={int(metrics['num_selected'])} "
+            f"cold={int(metrics['cold_starts'])} "
             f"latency={float(metrics['round_latency_ms']):.0f}ms "
             f"energy={float(metrics['energy_j']):.1f}J "
             + (
@@ -351,13 +383,15 @@ def _train_loop(args, fl_cfg, data_cfg, tel_cfg, round_fn, state, telemetry,
                 if fl_cfg.faults is not None
                 else ""
             )
-            + f"({time.time() - t0:.2f}s)",
+            + f"({round_wall_s + tel.seconds:.2f}s)",
             flush=True,
         )
         if checkpointer and (r + 1) % args.ckpt_every == 0:
-            checkpointer.save(r + 1, state)
+            with spans.span("fedfog.checkpoint"):
+                checkpointer.save(r + 1, state)
     if checkpointer:
-        checkpointer.wait()
+        with spans.span("fedfog.checkpoint"):
+            checkpointer.wait()
     tracker.log_summary(
         {"arch": args.arch, "scale": args.scale,
          "rounds": args.rounds - start_round,
@@ -371,14 +405,17 @@ def _sharded_round_fn(args, cfg, model, fl_cfg, rules, flops_round):
     abstract inputs (so --compile-only never allocates parameters and
     round 0 doesn't re-trace), and enforce the paper's communication
     contract: exactly ONE inter-client all-reduce (the Eq. 6 delta
-    aggregation) in the compiled round body. Returns the compiled
-    executable."""
+    aggregation) in the compiled round body. Returns a
+    :class:`RoundProgram`, whose phase map is also registered with
+    ``repro.obs.spans``. The compile keys its cache entry on the program's
+    metadata, so that the map survives a warm cache."""
     import jax
     import jax.numpy as jnp
 
     from repro.dist import analyze_hlo
     from repro.dist.hlo_analysis import assert_inter_client_contract
     from repro.fl import abstract_fl_state, make_round_fn
+    from repro.launch.compile_cache import metadata_in_key
     from repro.models import Runtime
 
     mesh_shape = rules.mesh.shape
@@ -421,9 +458,12 @@ def _sharded_round_fn(args, cfg, model, fl_cfg, rules, flops_round):
         round_fn, in_shardings=(state_sh, batch_sh),
         out_shardings=(state_sh, None), donate_argnums=(0,),
     )
-    t0 = time.time()
-    compiled = jitted.lower(state_abs, batch_abs).compile()
-    print(f"[train] sharded round compiled in {time.time() - t0:.1f}s")
+    with spans.span("fedfog.setup.lower") as lower:
+        lowered = jitted.lower(state_abs, batch_abs)
+    with spans.span("fedfog.setup.compile") as compile_, metadata_in_key():
+        compiled = lowered.compile()
+    print(f"[train] sharded round compiled in "
+          f"{lower.seconds + compile_.seconds:.1f}s")
     mem = compiled.memory_analysis()
     if mem is not None:
         print(f"[train] device memory per chip: "
@@ -432,28 +472,32 @@ def _sharded_round_fn(args, cfg, model, fl_cfg, rules, flops_round):
               f"out={mem.output_size_in_bytes / 1e9:.2f} GB "
               f"alias={mem.alias_size_in_bytes / 1e9:.2f} GB")
 
-    hlo = analyze_hlo(compiled.as_text())
-    stats = hlo.collectives
-    print(f"[train] collectives: {stats.count_by_kind} "
-          f"bytes={ {k: f'{v:.2e}' for k, v in stats.bytes_by_kind.items()} }")
-    for w in stats.trip_count_warnings[:3]:
-        print(f"[train] note: {w}")
+    with spans.span("fedfog.setup.contract"):
+        hlo = analyze_hlo(compiled.as_text())
+        stats = hlo.collectives
+        print(f"[train] collectives: {stats.count_by_kind} bytes="
+              f"{ {k: f'{v:.2e}' for k, v in stats.bytes_by_kind.items()} }")
+        for w in stats.trip_count_warnings[:3]:
+            print(f"[train] note: {w}")
 
-    # Raises on violation — holds on both the reference aggregation and
-    # the sharded delta-pipeline kernel path (--pallas-agg). With a fog
-    # tier on the kernel path the contract is per-tier (edge psum + fog
-    # psum); the reference fog path is GSPMD-scheduled and legally
-    # fuses back to the flat single all-reduce.
-    contract_fog = fl_cfg.fog_nodes if fl_cfg.use_pallas_agg else 1
-    _, delta_bytes = assert_inter_client_contract(
-        hlo, rules, model.param_count(), fog_nodes=contract_fog
-    )
+        # Raises on violation — holds on both the reference aggregation
+        # and the sharded delta-pipeline kernel path (--pallas-agg). With
+        # a fog tier on the kernel path the contract is per-tier (edge
+        # psum + fog psum); the reference fog path is GSPMD-scheduled and
+        # legally fuses back to the flat single all-reduce.
+        contract_fog = fl_cfg.fog_nodes if fl_cfg.use_pallas_agg else 1
+        _, delta_bytes = assert_inter_client_contract(
+            hlo, rules, model.param_count(), fog_nodes=contract_fog
+        )
     if rules.client_ways > 1:
         tiers = ("one delta all-reduce PER TIER (edge+fog)"
                  if contract_fog > 1 else "ONE inter-client all-reduce")
         print(f"[train] verified: {tiers} "
               f"({delta_bytes:.2e} B delta payload)")
-    return compiled
+    print(f"[train] phase map: {len(hlo.phases)} instructions in "
+          f"{sorted(set(hlo.phases.values()))}")
+    spans.register_program(hlo.module, hlo.phases, hlo.heads)
+    return RoundProgram(compiled, hlo.phases, hlo.module)
 
 
 if __name__ == "__main__":

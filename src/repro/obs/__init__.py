@@ -1,9 +1,11 @@
 """repro.obs — streaming observability for the compiled engines.
 
 Pluggable trackers (``trackers``), in-scan ``io_callback`` metric taps
-(``tap``), and the shared history/summary schema (``history``). See
-docs/EXPERIMENTS.md §Observability for the event/column ↔ §IV.F metric
-map and the CLI surface (``--track jsonl:PATH``).
+(``tap``), the shared history/summary schema (``history``), and host
+spans on the profiler's clock with per-name aggregates and the round's
+phase map (``spans``). See docs/EXPERIMENTS.md §Observability for the
+event/column ↔ §IV.F metric map, the span and scope names, and the CLI
+surface (``--track jsonl:PATH``).
 """
 from repro.obs.history import (
     assemble_async_history,

@@ -11,6 +11,11 @@ The topology is described only inside the module fixture: loading the TPU
 compiler's library takes a process-wide lock, so it must not happen while
 any module is imported.
 """
+import json
+import os
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,3 +112,100 @@ def test_paged_attention_compiles_at_hymba_widths(one_chip, window, dtype):
     ).compile()
     _assert_kernel(compiled)
 
+
+
+def _kernel_heads(compiled):
+    """Names of the compiled program's Pallas custom calls, suffix cut."""
+    return {
+        re.sub(r"\.\d+$", "", m.group(1))
+        for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? custom-call\(.*"
+                             r'custom_call_target="tpu_custom_call"',
+                             compiled.as_text(), re.M)
+    }
+
+
+def _kernel_programs(one_chip):
+    """(kernel name, compile thunk) for each ``pallas_call`` of the
+    repository, at small shapes."""
+    from repro.kernels.delta_pipeline import delta_pipeline_partial
+    from repro.kernels.fedavg.fedavg import fedavg_apply
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_fwd,
+    )
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    c, p = 2, 1 << 16
+    s = lambda shape, dt=f32: _spec(one_chip, shape, dt)  # noqa: E731
+    dp = lambda **kw: delta_pipeline_apply.lower(  # noqa: E731
+        s((c, p)), s((p,)), s((c,), jnp.bool_), s((c,)), 1.0,
+        interpret=False, **kw).compile()
+    return {
+        # The FedAvgM apply kernel: ``delta_pipeline_roofline`` reads it by
+        # this head.
+        "delta_pipeline_apply": lambda: dp(
+            momentum=s((p,)), server_optimizer="fedavgm"),
+        "delta_sq_norms": lambda: dp(clip_norm=1.0),
+        "delta_pipeline_partial": lambda: delta_pipeline_partial.lower(
+            s((c, p)), s((c,)), interpret=False).compile(),
+        "fedavg_apply": lambda: jax.jit(
+            lambda u, b, m, w: fedavg_apply(u, b, m, w, interpret=False)
+        ).lower(s((c, p)), s((p,)), s((c,), jnp.bool_), s((c,))).compile(),
+        "flash_attention": lambda: jax.jit(
+            lambda q, k, v: flash_attention_fwd(q, k, v, interpret=False)
+        ).lower(*[s((1, 2, 256, 64), bf16)] * 3).compile(),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "delta_pipeline_apply", "delta_sq_norms", "delta_pipeline_partial",
+    "fedavg_apply", "flash_attention"])
+def test_every_kernel_is_named_in_the_compiled_program(one_chip, kernel):
+    """A trace names each Pallas kernel after its ``pallas_call`` name."""
+    assert kernel in _kernel_heads(_kernel_programs(one_chip)[kernel]())
+
+
+def test_paged_attention_kernel_is_named(one_chip):
+    cfg = get_config("hymba-1.5b")
+    pool = (9, cfg.num_kv_heads, 16, cfg.head_dim)
+    compiled = jax.jit(
+        lambda q, k, v, t, l: paged_attention(q, k, v, t, l, -1,
+                                              interpret=False)
+    ).lower(
+        _spec(one_chip, (2, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+        _spec(one_chip, pool, jnp.bfloat16), _spec(one_chip, pool, jnp.bfloat16),
+        _spec(one_chip, (2, 4), jnp.int32), _spec(one_chip, (2,), jnp.int32),
+    ).compile()
+    assert "paged_attention" in _kernel_heads(compiled)
+
+
+def test_phase_map_heads_match_the_recorded_chip_trace(one_chip):
+    """The benchmark's recorded trace ran ``tanh(x @ x)`` on a v5e. The
+    same program, compiled here under a ``fedfog.server`` scope, maps its
+    fusion with the head that the trace's event prints, and the
+    benchmark's phase reader finds the fusion's time there."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    sys.path.insert(0, bench)
+    try:
+        import phases
+        import xplane
+    finally:
+        sys.path.remove(bench)
+    from repro.dist import analyze_hlo
+
+    def f(x):
+        with jax.named_scope("fedfog.server"):
+            return jnp.tanh(x @ x)
+
+    compiled = jax.jit(f).lower(
+        _spec(one_chip, (1024, 1024), jnp.bfloat16)).compile()
+    hlo = analyze_hlo(compiled.as_text())
+    data = os.path.join(bench, "tests", "data", "trace")
+    with open(os.path.join(data, "expected.json")) as fh:
+        want = json.load(fh)
+    fusion = xplane.op_head(want["op"])
+    assert hlo.phases[fusion] == "fedfog.server"
+    assert phases.signature(want["op"]) == hlo.heads[fusion]
+    prog = {"module": hlo.module, "phases": hlo.phases, "heads": hlo.heads}
+    got = phases.phase_seconds(xplane.read(data), prog, "fedfog.server")
+    assert got == pytest.approx(want["op_seconds"], rel=1e-9)

@@ -156,3 +156,23 @@ def test_fedavg_all_masked_is_safe():
     out = fedavg_apply(upd, base, jnp.zeros(4, bool), jnp.ones(4))
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
+
+
+def test_wkv6_pallas_call_is_named():
+    """The kernel carries its own name into the program (a TPU trace names
+    the custom call after it); checked on the traced program, since the
+    TPU lowering of this kernel's cumsum is not implemented."""
+    x = jnp.zeros((1, 32, 1, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda r, k, v, w, u: wkv6_fwd(r, k, v, w, u, interpret=True)
+    )(x, x, x, x, jnp.zeros((1, 64), jnp.float32))
+
+    def names(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"]
+            for v in e.params.values():
+                if hasattr(v, "jaxpr"):  # a nested (closed) jaxpr
+                    yield from names(getattr(v.jaxpr, "jaxpr", v.jaxpr))
+
+    assert list(names(jaxpr.jaxpr)) == ["wkv6"]

@@ -304,3 +304,42 @@ def test_sweep_tracker_events_and_cache_hits():
     run_sweep(cfg, seeds=range(2), axes={"lr": [0.01, 0.05]}, tracker=mt2)
     assert [r["cache_hit"] for r in mt2.rows
             if r["event"] == "sweep_group"] == [True]
+
+
+# --------------------------------------------------------------------- #
+# host spans (repro.obs.spans)
+# --------------------------------------------------------------------- #
+def test_span_aggregates_count_total_longest_and_nest():
+    import time
+
+    from repro.obs.spans import Recorder
+
+    rec = Recorder()
+    with rec.span("outer") as outer:
+        for pause in (0.01, 0.03):
+            with rec.span("inner") as inner:
+                time.sleep(pause)
+            assert inner.seconds >= pause
+    st = rec.stats()
+    assert st["inner"].count == 2 and st["outer"].count == 1
+    assert st["inner"].longest_s == st["inner"].last_s >= 0.03
+    assert st["inner"].total_s >= 0.04
+    assert st["outer"].total_s == outer.seconds >= st["inner"].total_s
+    st["inner"].count = 99  # a copy: the recorder's aggregate is untouched
+    assert rec.stats()["inner"].count == 2
+    rec.register_program("jit_f", {"a": "fedfog.server"}, {"a": "f32[] add"})
+    assert rec.programs() == [{"module": "jit_f",
+                               "phases": {"a": "fedfog.server"},
+                               "heads": {"a": "f32[] add"}}]
+    rec.reset()
+    assert rec.stats() == {} and rec.programs() == []
+
+
+def test_span_closes_and_counts_when_its_body_raises():
+    from repro.obs.spans import Recorder
+
+    rec = Recorder()
+    with pytest.raises(ValueError):
+        with rec.span("fails"):
+            raise ValueError("boom")
+    assert rec.stats()["fails"].count == 1
