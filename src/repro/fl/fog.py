@@ -294,8 +294,11 @@ def fog_pipeline_apply(
     tables are fog-local, like the sharded kernel's shard-local ones);
     the cloud combines the F partials and runs the shared replicated
     epilogue. Same return convention as ``delta_pipeline_apply``.
+    ``block_d=None`` lets each pass take the kernel family's tile, which
+    it derives from the fog block's client count and P
+    (``kernels.delta_pipeline.delta_pipeline.tile_columns``) and whose
+    ragged last block it masks; an int forces the tile.
     """
-    from repro.kernels.delta_pipeline.delta_pipeline import DEFAULT_BLOCK_D
     from repro.kernels.delta_pipeline.ops import delta_pipeline_partial
     from repro.kernels.delta_pipeline.sharded import combine_epilogue
 
@@ -304,7 +307,6 @@ def fog_pipeline_apply(
         raise ValueError(
             f"client count {c} not divisible by fog_nodes {fog_nodes}"
         )
-    block_d = DEFAULT_BLOCK_D if block_d is None else block_d
     per_fog = c // fog_nodes
     has_mu = momentum is not None and server_optimizer in (
         "fedavgm", "fedadam"

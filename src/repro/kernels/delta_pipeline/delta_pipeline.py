@@ -15,9 +15,20 @@ whole chain into at most TWO passes over the delta stack:
     grid over D-tiles, accumulating per-client Σx² into a (C,) output.
   * ``delta_pipeline_apply`` — everything else in ONE pass: each D-tile
     is read once, transformed in VMEM (clip scale, quant/dequant or
-    top-k threshold mask), reduced with a single (1,C)×(C,bd) MXU
-    matmul, and combined with the (P,)-sized server-state tiles (base,
-    momentum, DP noise) that ride along at 1/C of the delta traffic.
+    top-k threshold mask), reduced over clients with C f32
+    multiply-adds on the VPU, and combined with the (P,)-sized
+    server-state tiles (base, momentum, DP noise) that ride along at
+    1/C of the delta traffic.
+
+The D-tile is derived, not fixed: ``tile_columns`` takes the most whole
+8,192-column chunks whose streamed blocks (deltas, and whichever of
+base, momentum, noise and segment ids the gates stream, inputs and
+outputs) fit a 24 MiB double-buffered VMEM budget — about 4 MiB of f32
+deltas a grid step at C = 2, 1,042 steps over the rwkv6-1.6b round's
+P. The kernels ask the compiler for 32 MiB of scoped VMEM and compute a
+step one chunk at a time. P need not be a multiple of the tile: the
+last block is ragged, and the kernel handles it (masked out of the
+norms, written back only up to P) instead of padding any operand.
 
 Per-client scalars (clip scales, staleness discounts, Eq. 6 weights)
 travel in tiny (1, C) vectors; per-(client, leaf) compression scales /
@@ -48,43 +59,113 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pallas_compat import interpret_default
 
-DEFAULT_BLOCK_D = 2048
 _EPS = 1e-12  # matches core.aggregation._EPS / sim.events.staleness
+
+# The streaming tile, shared by every kernel of the family. A grid step
+# moves a block of ``block_d`` columns of every streamed operand; the
+# grid has cdiv(P, block_d) steps, and the last, ragged block is read
+# past P (values the kernels never let reach a kept result) and written
+# back only up to P, so no operand is padded. Inside a step the block is
+# computed a chunk of columns at a time, so the kernel's code and
+# temporaries are a chunk's size whatever the block's.
+_CHUNK = 8192  # the widest chunk: a multiple of the 1-D f32, int32, bf16 tiles
+_CHUNK_ELEMS = 1 << 17  # one (client rows, chunk) f32 temporary: 512 KiB
+_VMEM_BLOCKS = 24 << 20  # one step's streamed blocks, double-buffered
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary",),
+    vmem_limit_bytes=32 << 20,  # the blocks + the chunks' temporaries
+)
+
+
+def chunk_columns(rows: int) -> int:
+    """Columns a kernel computes at once over ``rows`` client rows (C,
+    or the selection network's power-of-two padded rows): 8,192 up to
+    16 rows, halved as the rows double past that, at least 1,024."""
+    chunk = _CHUNK
+    while chunk > 1024 and rows * chunk > _CHUNK_ELEMS:
+        chunk //= 2
+    return chunk
+
+
+def tile_columns(d: int, column_bytes: int, rows: int,
+                 block_d: int | None = None) -> int:
+    """Columns a grid step streams over a P = ``d`` axis.
+
+    ``column_bytes``: what one column of every streamed operand, inputs
+    and outputs, holds — C times the delta itemsize, plus each (P,)
+    vector's. The tile is the most whole chunks (``chunk_columns(rows)``)
+    whose blocks, double buffered, fit ``_VMEM_BLOCKS`` (FedAvgM at
+    C = 2 and f32: 24 B a column, 512Ki columns, 4 MiB of deltas a
+    step), and no more whole chunks than P holds; a P of one chunk or
+    less is one block. ``block_d`` overrides the rule (tests force many
+    tiles at small P).
+    """
+    if block_d is not None:
+        return min(block_d, d)
+    chunk = chunk_columns(rows)
+    if d <= chunk:
+        return d
+    fit = _VMEM_BLOCKS // (2 * column_bytes) // chunk * chunk
+    return max(chunk, min(fit, d // chunk * chunk))
+
+
+def _for_each_chunk(block: int, rows: int, on_chunk) -> None:
+    """Call ``on_chunk(cols)`` over a ``block``-column grid step, one
+    chunk of columns at a time (the whole block when it is no chunk
+    multiple, as an explicit small tile is)."""
+    chunk = chunk_columns(rows)
+    if block % chunk:
+        chunk = block
+
+    def body(j, carry):
+        on_chunk(pl.ds(pl.multiple_of(j * chunk, chunk), chunk))
+        return carry
+
+    jax.lax.fori_loop(0, block // chunk, body, 0)
 
 
 # --------------------------------------------------------------------- #
 # pass 1: per-client squared norms (the clip reduction)
 # --------------------------------------------------------------------- #
-def _sq_norms_kernel(upd_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+def _make_sq_norms_kernel(d: int, block: int):
+    def kernel(upd_ref, out_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = upd_ref[...].astype(jnp.float32)
-    out_ref[...] = out_ref[...] + jnp.sum(x * x, axis=1)
+        start = pl.program_id(0) * block
+
+        def on_chunk(cols):
+            x = upd_ref[:, cols].astype(jnp.float32)
+            # The ragged last block reads past P: mask those columns.
+            col = start + cols.start + jax.lax.broadcasted_iota(
+                jnp.int32, x.shape, 1
+            )
+            x = jnp.where(col < d, x, 0.0)
+            out_ref[...] = out_ref[...] + jnp.sum(x * x, axis=1)
+
+        _for_each_chunk(block, upd_ref.shape[0], on_chunk)
+
+    return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def delta_sq_norms(
     updates: jax.Array,  # (C, P)
-    block_d: int = DEFAULT_BLOCK_D,
+    block_d: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-client Σx² over the fused delta buffer — one HBM pass."""
     interpret = interpret_default(interpret)
     c, d = updates.shape
-    block_d = min(block_d, d)
-    pad = (-d) % block_d
-    if pad:
-        updates = jnp.pad(updates, ((0, 0), (0, pad)))
-    grid = ((d + pad) // block_d,)
+    block_d = tile_columns(d, c * updates.dtype.itemsize, c, block_d)
     return pl.pallas_call(
-        _sq_norms_kernel,
-        grid=grid,
+        _make_sq_norms_kernel(d, block_d),
+        grid=(pl.cdiv(d, block_d),),
         in_specs=[pl.BlockSpec((c, block_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((c,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((c,), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=_COMPILER_PARAMS,
         name="delta_sq_norms",
         interpret=interpret,
     )(updates)
@@ -93,10 +174,11 @@ def delta_sq_norms(
 # --------------------------------------------------------------------- #
 # shared tile transform (clip scale + compression expansion)
 # --------------------------------------------------------------------- #
-def _transform_tile(x, pre_ref, seg_ref, tab_ref, compression, n_leaves):
-    """The per-tile pre-aggregation transform, shared by the full
-    pipeline kernel, the sharded partial-sum kernel and the selection
-    kernels: optional clip pre-scale, then compression emulation via a
+def _transform_tile(x, cols, pre_ref, seg_ref, tab_ref, compression,
+                    n_leaves):
+    """The per-tile pre-aggregation transform of the ``cols`` columns,
+    shared by the full pipeline kernel and the sharded partial-sum
+    kernel: optional clip pre-scale, then compression emulation via a
     static ``n_leaves``-way select chain over the (C, L) table."""
     if pre_ref is not None:
         x = x * pre_ref[0, :][:, None]
@@ -104,9 +186,9 @@ def _transform_tile(x, pre_ref, seg_ref, tab_ref, compression, n_leaves):
         # Expand the (C, L) per-leaf table to per-column values with
         # a static L-way select chain — no dynamic gather, so the
         # tile stays VPU-only on TPU.
-        seg = seg_ref[...]  # (bd,) int32 leaf-segment ids
+        seg = seg_ref[cols]  # int32 leaf-segment ids
         tab = tab_ref[...].astype(jnp.float32)  # (C, L)
-        col = jnp.ones(x.shape, jnp.float32)  # pad columns: benign 1.0
+        col = jnp.ones(x.shape, jnp.float32)
         for l in range(n_leaves):
             col = jnp.where((seg == l)[None, :], tab[:, l][:, None], col)
         if compression == "int8":
@@ -115,6 +197,25 @@ def _transform_tile(x, pre_ref, seg_ref, tab_ref, compression, n_leaves):
         else:  # topk: col holds the kth-largest |x| per (client, leaf)
             x = x * (jnp.abs(x) >= col).astype(jnp.float32)
     return x
+
+
+def _weighted_sum(w, x):
+    """Eq. 6's client sum of one (C, chunk) tile under the (1, C) weight
+    row ``w``: C multiply-adds in f32 on the VPU, in client order (an
+    MXU dot at HIGHEST precision takes several passes for its one output
+    row and would set the kernel's pace). Written product-first, so that
+    a backend that fuses multiply-adds fuses each product into the
+    running sum, as an f32 dot does."""
+    acc = x[0:1] * w[:, 0:1]
+    for k in range(1, x.shape[0]):
+        acc = x[k:k + 1] * w[:, k:k + 1] + acc
+    return acc[0]
+
+
+def _network_rows(c: int) -> int:
+    """Rows of the selection network over C clients: C padded to a power
+    of two."""
+    return 1 << max((c - 1).bit_length(), 0)
 
 
 def _bitonic_sort(rows):
@@ -154,7 +255,7 @@ def _select_aggregate(x, wn, cnt, aggregator):
     participation row and ``cnt`` the (1, 2) int32 [num_sel, k_trim]
     pair, traced data so participation masks stay dynamic."""
     c = x.shape[0]
-    n2 = 1 << max((c - 1).bit_length(), 0)
+    n2 = _network_rows(c)
     inf_row = jnp.full((1,) + x.shape[1:], jnp.inf, x.dtype)
     rows = [
         jnp.where(wn[:, i:i + 1] > 0.0, x[i:i + 1], jnp.inf)
@@ -209,35 +310,35 @@ def _make_pipeline_kernel(
         out_ref = next(it)
         new_mu_ref = next(it) if has_mu else None
 
-        x = upd_ref[...].astype(jnp.float32)  # (C, bd)
-        x = _transform_tile(x, pre_ref, seg_ref, tab_ref, compression,
-                            n_leaves)
-        if robust:
-            agg = _select_aggregate(x, wn_ref[...], cnt_ref[...], aggregator)
-        else:
-            # HIGHEST: the MXU would otherwise round the f32 deltas to
-            # one bf16 pass (~2^-9 relative) on TPU; Eq. 6 is an f32 sum.
-            agg = jax.lax.dot_general(
-                wn_ref[0, :][None, :].astype(jnp.float32), x,
-                (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32,
-            )[0]  # (bd,)
-        if has_dp:
-            agg = agg + noise_ref[...].astype(jnp.float32)
+        block = base_ref.shape[0]
         lr = lr_ref[0, 0].astype(jnp.float32)
-        if has_mu:
-            mu2 = server_momentum * mu_ref[...].astype(jnp.float32) + agg
-            new_mu_ref[...] = mu2.astype(new_mu_ref.dtype)
-            if server_optimizer == "fedadam":
-                step = lr * mu2 / (jnp.sqrt(jnp.square(agg)) + 1e-3)
-            else:  # fedavgm
-                step = lr * mu2
-        else:
-            step = lr * agg
-        out_ref[...] = (
-            base_ref[...].astype(jnp.float32) + step
-        ).astype(out_ref.dtype)
+
+        def on_chunk(cols):
+            x = upd_ref[:, cols].astype(jnp.float32)  # (C, chunk)
+            x = _transform_tile(x, cols, pre_ref, seg_ref, tab_ref,
+                                compression, n_leaves)
+            if robust:
+                agg = _select_aggregate(x, wn_ref[...], cnt_ref[...],
+                                        aggregator)
+            else:
+                agg = _weighted_sum(wn_ref[...].astype(jnp.float32), x)
+            if has_dp:
+                agg = agg + noise_ref[cols].astype(jnp.float32)
+            if has_mu:
+                mu2 = server_momentum * mu_ref[cols].astype(jnp.float32) + agg
+                new_mu_ref[cols] = mu2.astype(new_mu_ref.dtype)
+                if server_optimizer == "fedadam":
+                    step = lr * mu2 / (jnp.sqrt(jnp.square(agg)) + 1e-3)
+                else:  # fedavgm
+                    step = lr * mu2
+            else:
+                step = lr * agg
+            out_ref[cols] = (
+                base_ref[cols].astype(jnp.float32) + step
+            ).astype(out_ref.dtype)
+
+        c = upd_ref.shape[0]
+        _for_each_chunk(block, _network_rows(c) if robust else c, on_chunk)
 
     return kernel
 
@@ -313,7 +414,7 @@ def delta_pipeline_apply(
     server_optimizer: str = "fedavg",  # fedavg | fedavgm | fedadam
     server_momentum: float = 0.9,
     aggregator: str = "fedavg",  # fedavg | median | trimmed
-    block_d: int = DEFAULT_BLOCK_D,
+    block_d: int | None = None,  # None: the tile rule (tile_columns)
     interpret: bool | None = None,
 ):
     """One-pass fused delta pipeline over the (C, P) buffer.
@@ -335,8 +436,6 @@ def delta_pipeline_apply(
     """
     interpret = interpret_default(interpret)
     c, d = updates.shape
-    block_d = min(block_d, d)
-    pad = (-d) % block_d
     if compression not in ("none", "int8", "topk"):
         raise ValueError(f"unknown compression {compression!r}")
     if compression != "none" and seg_sizes is None:
@@ -355,6 +454,17 @@ def delta_pipeline_apply(
         "fedavgm", "fedadam"
     )
     has_dp = dp_noise is not None
+    # Streamed bytes a column: the deltas, base in and out, and the
+    # segment ids, noise and momentum in and out where their gates run.
+    column_bytes = c * updates.dtype.itemsize + 2 * base.dtype.itemsize
+    if compression != "none":
+        column_bytes += 4
+    if has_dp:
+        column_bytes += dp_noise.dtype.itemsize
+    if has_mu:
+        column_bytes += 2 * momentum.dtype.itemsize
+    rows = _network_rows(c) if robust else c
+    bd = tile_columns(d, column_bytes, rows, block_d)
 
     # -- per-client scalars: Eq. 6 weights, staleness, clip scales ------ #
     if robust:
@@ -389,9 +499,6 @@ def delta_pipeline_apply(
         norm = jnp.sqrt(delta_sq_norms(updates, block_d, interpret))
         pre = jnp.minimum(1.0, clip_norm / jnp.maximum(norm, 1e-12))
 
-    def padded(x):  # pad the P axis out to a block multiple
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
-
     inputs = [wn[None, :]]
     in_specs = [pl.BlockSpec((1, c), lambda i: (0, 0))]
     if robust:
@@ -399,13 +506,13 @@ def delta_pipeline_apply(
         in_specs.append(pl.BlockSpec((1, 2), lambda i: (0, 0)))
     inputs += [
         jnp.asarray(lr, jnp.float32).reshape(1, 1),
-        padded(updates),
-        padded(base),
+        updates,
+        base,
     ]
     in_specs += [
         pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        pl.BlockSpec((c, block_d), lambda i: (0, i)),
-        pl.BlockSpec((block_d,), lambda i: (i,)),
+        pl.BlockSpec((c, bd), lambda i: (0, i)),
+        pl.BlockSpec((bd,), lambda i: (i,)),
     ]
     n_leaves = len(seg_sizes) if seg_sizes else 0
     if pre is not None:
@@ -418,25 +525,23 @@ def delta_pipeline_apply(
         tab = segment_table(
             updates, compression, topk_fraction, seg_sizes, pre=pre
         )
-        inputs += [padded(seg), tab]
+        inputs += [seg, tab]
         in_specs += [
-            pl.BlockSpec((block_d,), lambda i: (i,)),
+            pl.BlockSpec((bd,), lambda i: (i,)),
             pl.BlockSpec((c, n_leaves), lambda i: (0, 0)),
         ]
     if has_dp:
-        inputs.append(padded(dp_noise))
-        in_specs.append(pl.BlockSpec((block_d,), lambda i: (i,)))
+        inputs.append(dp_noise)
+        in_specs.append(pl.BlockSpec((bd,), lambda i: (i,)))
     if has_mu:
-        inputs.append(padded(momentum))
-        in_specs.append(pl.BlockSpec((block_d,), lambda i: (i,)))
+        inputs.append(momentum)
+        in_specs.append(pl.BlockSpec((bd,), lambda i: (i,)))
 
-    dp_total = d + pad
-    grid = (dp_total // block_d,)
-    out_shape = [jax.ShapeDtypeStruct((dp_total,), base.dtype)]
-    out_specs = [pl.BlockSpec((block_d,), lambda i: (i,))]
+    out_shape = [jax.ShapeDtypeStruct((d,), base.dtype)]
+    out_specs = [pl.BlockSpec((bd,), lambda i: (i,))]
     if has_mu:
-        out_shape.append(jax.ShapeDtypeStruct((dp_total,), momentum.dtype))
-        out_specs.append(pl.BlockSpec((block_d,), lambda i: (i,)))
+        out_shape.append(jax.ShapeDtypeStruct((d,), momentum.dtype))
+        out_specs.append(pl.BlockSpec((bd,), lambda i: (i,)))
 
     kernel = _make_pipeline_kernel(
         n_leaves, pre is not None, compression, has_dp, has_mu,
@@ -444,17 +549,15 @@ def delta_pipeline_apply(
     )
     outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(d, bd),),
         in_specs=in_specs,
         out_specs=out_specs if has_mu else out_specs[0],
         out_shape=out_shape if has_mu else out_shape[0],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=_COMPILER_PARAMS,
         name="delta_pipeline_apply",
         interpret=interpret,
     )(*inputs)
-    if has_mu:
-        return outs[0][:d], outs[1][:d]
-    return outs[:d]
+    return tuple(outs) if has_mu else outs
 
 
 # --------------------------------------------------------------------- #
@@ -470,15 +573,14 @@ def _make_partial_kernel(n_leaves: int, has_pre: bool, compression: str):
         tab_ref = next(it) if compression != "none" else None
         out_ref = next(it)
 
-        x = upd_ref[...].astype(jnp.float32)
-        x = _transform_tile(x, pre_ref, seg_ref, tab_ref, compression,
-                            n_leaves)
-        out_ref[...] = jax.lax.dot_general(
-            dm_ref[0, :][None, :].astype(jnp.float32), x,
-            (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,  # f32 sum, as above
-            preferred_element_type=jnp.float32,
-        )[0]
+        def on_chunk(cols):
+            x = upd_ref[:, cols].astype(jnp.float32)
+            x = _transform_tile(x, cols, pre_ref, seg_ref, tab_ref,
+                                compression, n_leaves)
+            out_ref[cols] = _weighted_sum(
+                dm_ref[...].astype(jnp.float32), x)
+
+        _for_each_chunk(out_ref.shape[0], upd_ref.shape[0], on_chunk)
 
     return kernel
 
@@ -498,7 +600,7 @@ def delta_pipeline_partial(
     compression: str = "none",
     topk_fraction: float = 0.05,
     seg_sizes: tuple[int, ...] | None = None,
-    block_d: int = DEFAULT_BLOCK_D,
+    block_d: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-shard half of the sharded pipeline: clip + compression +
@@ -509,21 +611,21 @@ def delta_pipeline_partial(
     the (P,) partial plus the Σdm / Σm scalars → exactly one psum."""
     interpret = interpret_default(interpret)
     c, d = updates.shape
-    block_d = min(block_d, d)
-    pad = (-d) % block_d
+    # Streamed bytes a column: the deltas, the f32 partial, segment ids.
+    column_bytes = c * updates.dtype.itemsize + 4
+    if compression != "none":
+        column_bytes += 4
+    bd = tile_columns(d, column_bytes, c, block_d)
 
     pre = None
     if clip_norm and clip_norm > 0:
         norm = jnp.sqrt(delta_sq_norms(updates, block_d, interpret))
         pre = jnp.minimum(1.0, clip_norm / jnp.maximum(norm, 1e-12))
 
-    def padded(x):
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
-
-    inputs = [dm[None, :].astype(jnp.float32), padded(updates)]
+    inputs = [dm[None, :].astype(jnp.float32), updates]
     in_specs = [
         pl.BlockSpec((1, c), lambda i: (0, 0)),
-        pl.BlockSpec((c, block_d), lambda i: (0, i)),
+        pl.BlockSpec((c, bd), lambda i: (0, i)),
     ]
     n_leaves = len(seg_sizes) if seg_sizes else 0
     if pre is not None:
@@ -536,22 +638,20 @@ def delta_pipeline_partial(
         tab = segment_table(
             updates, compression, topk_fraction, seg_sizes, pre=pre
         )
-        inputs += [padded(seg), tab]
+        inputs += [seg, tab]
         in_specs += [
-            pl.BlockSpec((block_d,), lambda i: (i,)),
+            pl.BlockSpec((bd,), lambda i: (i,)),
             pl.BlockSpec((c, n_leaves), lambda i: (0, 0)),
         ]
 
-    dp_total = d + pad
     kernel = _make_partial_kernel(n_leaves, pre is not None, compression)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(dp_total // block_d,),
+        grid=(pl.cdiv(d, bd),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((dp_total,), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        out_specs=pl.BlockSpec((bd,), lambda i: (i,)),
+        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         name="delta_pipeline_partial",
         interpret=interpret,
     )(*inputs)
-    return out[:d]

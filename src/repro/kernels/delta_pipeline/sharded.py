@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.delta_pipeline.delta_pipeline import (
-    DEFAULT_BLOCK_D,
     _EPS,
     delta_pipeline_apply,
     delta_pipeline_partial,
@@ -142,7 +141,7 @@ def delta_pipeline_apply_sharded(
     seg_sizes: tuple[int, ...] | None = None,
     server_optimizer: str = "fedavg",
     server_momentum: float = 0.9,
-    block_d: int = DEFAULT_BLOCK_D,
+    block_d: int | None = None,
     interpret: bool | None = None,
 ):
     """Sharded fused delta pipeline: one HBM pass per shard, one psum

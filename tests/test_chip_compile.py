@@ -62,17 +62,33 @@ def _assert_kernel(compiled):
 
 def test_delta_pipeline_compiles_at_the_lm_round_shape(one_chip):
     """FedAvgM gates at the (C, P) of the rwkv6-1.6b round one chip
-    holds: 2 slots, depth cut to 5 layers (545.8M parameters)."""
+    holds: 2 slots, depth cut to 5 layers (545.8M parameters). The
+    kernel compiles under its derived tile and VMEM limit, in at most
+    4,096 grid steps, with no copy of a P-long operand around it."""
     c = 2
     p = build_model(get_config("rwkv6-1.6b").with_depth(5)).param_count()
     f32 = jnp.float32
-    compiled = delta_pipeline_apply.lower(
-        _spec(one_chip, (c, p), f32), _spec(one_chip, (p,), f32),
-        _spec(one_chip, (c,), jnp.bool_), _spec(one_chip, (c,), f32),
-        1.0, momentum=_spec(one_chip, (p,), f32),
-        server_optimizer="fedavgm", interpret=False,
-    ).compile()
+    shapes = [((c, p), f32), ((p,), f32), ((c,), jnp.bool_), ((c,), f32),
+              ((p,), f32)]
+
+    def apply(u, b, m, w, mu):
+        return delta_pipeline_apply(u, b, m, w, 1.0, momentum=mu,
+                                    server_optimizer="fedavgm",
+                                    interpret=False)
+
+    compiled = jax.jit(apply).lower(
+        *[_spec(one_chip, a, t) for a, t in shapes]).compile()
     _assert_kernel(compiled)
+    big = [m.group(0) for m in re.finditer(
+        r"\[([\d,]+)\]\S* (?:pad|copy)\(", compiled.as_text())
+        if max(map(int, m.group(1).split(","))) >= p]
+    assert big == []
+    jaxpr = jax.make_jaxpr(apply)(
+        *[jax.ShapeDtypeStruct(a, t) for a, t in shapes])
+    (grid,) = [e.params["grid_mapping"].grid
+               for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(grid) == 1 and grid[0] <= 4096
 
 
 @pytest.mark.parametrize("aggregator", ["median", "trimmed"])

@@ -15,6 +15,7 @@ import itertools
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jax_core
 import numpy as np
 import pytest
 
@@ -30,17 +31,32 @@ from repro.fl.fuse import (
 from repro.fl.simulator import FedFogSimulator, SimulatorConfig
 from repro.kernels.delta_pipeline import (
     delta_pipeline_apply,
+    delta_pipeline_partial,
     delta_pipeline_ref,
     delta_sq_norms,
+)
+from repro.kernels.delta_pipeline.delta_pipeline import (
+    _CHUNK,
+    _CHUNK_ELEMS,
+    _VMEM_BLOCKS,
+    chunk_columns,
+    tile_columns,
 )
 
 KEY = jax.random.PRNGKey(7)
 
-# Two shape scales: "quick" exercises padding/odd segments, "full" is a
-# simulator-sized buffer (the MLP the paper-scale engine trains).
+# A P past two chunks and no multiple of the derived tile (two chunks
+# here): the default tile then leaves a ragged last block of 333 columns.
+RAGGED_P = 2 * _CHUNK + 333
+
+# Shape scales: "quick" forces many small tiles over odd segments,
+# "full" is a simulator-sized buffer (the MLP the paper-scale engine
+# trains), "derived" takes the default tile over a ragged P.
 SCALES = {
     "quick": dict(c=6, seg_sizes=(40, 8, 64, 16), block_d=64),
     "full": dict(c=32, seg_sizes=(784 * 16, 16, 16 * 62, 62), block_d=2048),
+    "derived": dict(c=6, seg_sizes=(3000, 8, RAGGED_P - 3024, 16),
+                    block_d=None),
 }
 
 
@@ -154,6 +170,108 @@ def test_pipeline_all_masked_is_safe():
     )
 
 
+def test_delta_sq_norms_masks_the_ragged_block():
+    """The derived tile over a ragged P: the interpreter fills the last
+    block past P with NaN, so an unmasked sum would read NaN."""
+    fx = _fixture(3, RAGGED_P)
+    out = delta_sq_norms(fx["upd"])
+    ref = jnp.sum(jnp.square(fx["upd"]), axis=1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+
+
+@pytest.mark.parametrize("p,block_d", [(1000, 256), (RAGGED_P, None)])
+@pytest.mark.parametrize("clip", [0.0, 1.5])
+def test_partial_matches_weighted_sum(p, block_d, clip):
+    """The sharded and fog tiers' partial sum over forced and derived
+    tiles, both with a ragged last block."""
+    fx = _fixture(4, p)
+    dm = fx["weights"] * fx["mask"]
+    out = delta_pipeline_partial(
+        fx["upd"], dm, clip_norm=clip, block_d=block_d
+    )
+    x = fx["upd"]
+    if clip:
+        norm = jnp.sqrt(jnp.sum(jnp.square(x), axis=1))
+        x = x * jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))[:, None]
+    ref = jnp.einsum("n,nd->d", dm, x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "d,column_bytes,rows",
+    [(545_787_904, 24, 2), (1 << 20, 16 * 4 + 8, 16), (RAGGED_P, 24, 2),
+     (100, 24, 2), (_CHUNK, 8, 2), (50_000_000, 8, 2),
+     (1 << 22, 64 * 4 + 8, 64), (1 << 22, 128 * 4 + 8, 128)],
+)
+def test_tile_rule(d, column_bytes, rows):
+    """The derived tile is whole chunks within the VMEM budget and P —
+    one block at P of a chunk or less — the chunk narrows as the client
+    rows grow, and the LM round's shape runs a few thousand grid
+    steps."""
+    bd = tile_columns(d, column_bytes, rows)
+    chunk = chunk_columns(rows)
+    assert rows * chunk <= _CHUNK_ELEMS or chunk == 1024
+    if d <= chunk:
+        assert bd == d
+        return
+    assert bd % chunk == 0 and chunk <= bd <= d
+    assert bd == chunk or 2 * bd * column_bytes <= _VMEM_BLOCKS
+    if d == 545_787_904:
+        assert -(-d // bd) <= 4096
+    assert tile_columns(d, column_bytes, rows, block_d=64) == 64
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, the
+    Pallas kernels' bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for j in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(j, jax_core.ClosedJaxpr):
+                    yield from _walk(j.jaxpr)
+                elif isinstance(j, jax_core.Jaxpr):
+                    yield from _walk(j)
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(server_optimizer="fedavgm", momentum=True),
+        dict(clip_norm=1.5, compression="int8", dp=True),
+        dict(compression="topk", server_optimizer="fedadam", momentum=True),
+        dict(aggregator="median"),
+    ],
+    ids=str,
+)
+def test_pipeline_pads_no_operand(kw):
+    """At a P that is no multiple of the tile, the lowered apply passes
+    the (C, P) deltas and the (P,) vectors to the kernel as they are:
+    the ragged last block is handled inside the kernel, not by a
+    ``jnp.pad`` copy of the operands."""
+    kw = dict(kw)
+    fx = _fixture(4, RAGGED_P)
+    momentum = fx["mu"] if kw.pop("momentum", False) else None
+    noise = fx["noise"] if kw.pop("dp", False) else None
+    if "compression" in kw:
+        kw["seg_sizes"] = (RAGGED_P - 100, 100)
+    jaxpr = jax.make_jaxpr(
+        lambda u, b, m, w: delta_pipeline_apply(
+            u, b, m, w, 0.7, None, 0.0, noise, momentum, **kw)
+    )(fx["upd"], fx["base"], fx["mask"], fx["weights"])
+    eqns = list(_walk(jaxpr.jaxpr))
+    pads = [e for e in eqns if e.primitive.name == "pad"
+            and RAGGED_P in e.invars[0].aval.shape]
+    assert pads == []
+    grids = [e.params["grid_mapping"].grid for e in eqns
+             if e.primitive.name == "pallas_call"
+             and e.params["name"] == "delta_pipeline_apply"]
+    assert grids == [(2,)]  # a block of two chunks, then the ragged 333
+
+
 # --------------------------------------------------------------------- #
 # in-kernel robust aggregators (median / trimmed) vs core.aggregation
 # --------------------------------------------------------------------- #
@@ -176,15 +294,22 @@ _MASKS = {
 }
 
 
-@pytest.mark.parametrize("c", [5, 6])  # odd + even client counts
+# odd + even client counts; small forced tiles, then the derived tile
+# over a ragged P
+@pytest.mark.parametrize(
+    "c,p,block_d",
+    [(5, 192, 64), (6, 192, 64), (5, RAGGED_P, None), (6, RAGGED_P, None)],
+    ids=["5", "6", "5-derived", "6-derived"],
+)
 @pytest.mark.parametrize("mask_kind", list(_MASKS))
 @pytest.mark.parametrize("agg,frac", [("median", 0.0), ("trimmed", 0.1),
                                       ("trimmed", 0.25)], ids=str)
-def test_robust_kernel_bitwise_matches_core(agg, frac, mask_kind, c):
+def test_robust_kernel_bitwise_matches_core(agg, frac, mask_kind, c, p,
+                                            block_d):
     """The in-kernel bitonic-selection median / trimmed mean is BITWISE
     equal to core.aggregation's jnp.sort-based references under masks
     (odd and even live counts)."""
-    fx = _fixture(c, 192)
+    fx = _fixture(c, p)
     mask = {
         "random": fx["mask"],
         "all": jnp.ones((c,), bool),
@@ -193,7 +318,7 @@ def test_robust_kernel_bitwise_matches_core(agg, frac, mask_kind, c):
     out = delta_pipeline_apply(
         fx["upd"], fx["base"], mask, fx["weights"], 0.7,
         None, 0.0, None, None, frac,
-        aggregator=agg, block_d=64,
+        aggregator=agg, block_d=block_d,
     )
     # jit the oracle (same FMA-fusion rationale as the gate matrix).
     exp = jax.jit(_core_robust, static_argnames="agg")(
